@@ -1,0 +1,18 @@
+"""Run one benchmark cell once (see ``benchmark/harness.py``).
+
+    python3 benchmark/run.py --workload <cell> --seed <n> \
+        --seconds <s> --trace <0|1>
+"""
+import time
+
+T0 = time.perf_counter()
+
+import os  # noqa: E402
+import sys  # noqa: E402
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark import harness  # noqa: E402
+
+if __name__ == "__main__":
+    sys.exit(harness.main(t0=T0))
