@@ -15,11 +15,12 @@ a chip window.  These lints reject it statically:
   best a no-op at trace time, at worst an io_callback-shaped stall baked
   into every iteration.
 * LUX-O002 — the flight recorder's HOST half (``obs.span`` /
-  ``obs.point`` / ``recorder()`` / ``ring_rows`` / ``emit_ring``) inside
-  a traced body.  Spans run at trace time there — the event log would
-  record compile-time, not run-time, and a retrace would duplicate it.
-  Inside compiled code the only legal telemetry API is ``ring_push`` on
-  a carried ring.
+  ``obs.point`` / ``obs.count`` / ``recorder()`` / ``ring_rows`` /
+  ``emit_ring``) inside a traced body.  Spans and counts run at trace
+  time there — the event log would record compile-time, not run-time,
+  and a retrace would duplicate it.  Inside compiled code the legal
+  telemetry is ``ring_push`` on a carried ring and ``jax.named_scope``
+  (op-name metadata, no runtime op).
 * LUX-O003 — per-iteration telemetry fetch: ``ring_rows``/``emit_ring``
   lexically inside a Python loop that also drives a compiled runner
   (``run_pull_fixed``/``run_pull_until``/``run_push``/a compiled
@@ -62,7 +63,7 @@ _CALLBACK_CALLS = {"jax.debug.print", "debug.print", "jax.debug.callback",
                    "jax.experimental.host_callback.call"}
 
 #: recorder-API member names, resolved against the obs-package aliases
-_RECORDER_MEMBERS = {"span", "point", "recorder"}
+_RECORDER_MEMBERS = {"span", "point", "count", "counters", "recorder"}
 #: ring HOST-fetch members (ring_push is the traced-side API and legal)
 _RING_FETCH_MEMBERS = {"ring_rows", "emit_ring"}
 

@@ -124,14 +124,18 @@ def pull_reduce_part(prog: PullProgram, arrays: ShardArrays, gath,
     the base arrays and the reduce itself run unchanged, so the overlay
     never retraces (LUX-J1)."""
     src_state, dst_state = gath
-    vals = prog.edge_value(src_state, arrays.weights, dst_state)
-    if del_val is not None:
-        from lux_tpu.mutate import overlay as _ovl
+    # the edge value fuses into the gather's kernel: its time is the
+    # gather's
+    with jax.named_scope("pull.gather"):
+        vals = prog.edge_value(src_state, arrays.weights, dst_state)
+        if del_val is not None:
+            from lux_tpu.mutate import overlay as _ovl
 
-        vals = _ovl.mask_deleted(vals, del_val, prog.reduce)
-    return _REDUCERS[prog.reduce](
-        vals, arrays.row_ptr, arrays.head_flag, arrays.dst_local, method=method
-    )
+            vals = _ovl.mask_deleted(vals, del_val, prog.reduce)
+    with jax.named_scope("pull.reduce"):
+        return _REDUCERS[prog.reduce](
+            vals, arrays.row_ptr, arrays.head_flag, arrays.dst_local,
+            method=method)
 
 
 def local_pull_step(
@@ -166,20 +170,34 @@ def local_pull_step(
         from lux_tpu.mutate.overlay import FUSED_OVERLAY_NOTE
 
         raise ValueError(FUSED_OVERLAY_NOTE)
-    if route is not None and isinstance(route[0], expand.CFRouteStatic):
-        gath = expand.apply_cf_route(full_state, local_state, route[0],
-                                     route[1], interpret=interpret)
-        acc = pull_reduce_part(prog, arrays, gath, method)
-        return prog.apply(local_state, acc, arrays)
     if route is not None and isinstance(route[0], expand.FusedStatic):
         assert route[0].reduce == prog.reduce, (
             f"fused plan was built for reduce={route[0].reduce!r} but the "
             f"program reduces with {prog.reduce!r}")
-        acc = expand.apply_fused(
-            full_state, route[0], route[1],
-            edge_value=lambda s, w: prog.edge_value(s, w, None),
-            interpret=interpret,
+        # one kernel gathers and reduces: its time is the reduce's
+        with jax.named_scope("pull.reduce"):
+            acc = expand.apply_fused(
+                full_state, route[0], route[1],
+                edge_value=lambda s, w: prog.edge_value(s, w, None),
+                interpret=interpret,
+                del_val=overlay[1].del_val if overlay is not None else None)
+    else:
+        with jax.named_scope("pull.gather"):
+            if route is not None and isinstance(route[0],
+                                                expand.CFRouteStatic):
+                gath = expand.apply_cf_route(full_state, local_state,
+                                             route[0], route[1],
+                                             interpret=interpret)
+            elif route is not None:
+                gath = pull_gather_part_routed(arrays, full_state,
+                                               local_state, route[0],
+                                               route[1], interpret)
+            else:
+                gath = pull_gather_part(arrays, full_state, local_state)
+        acc = pull_reduce_part(
+            prog, arrays, gath, method,
             del_val=overlay[1].del_val if overlay is not None else None)
+    with jax.named_scope("pull.apply"):
         if overlay is not None:
             from lux_tpu.mutate import overlay as _ovl
 
@@ -187,21 +205,6 @@ def local_pull_step(
                 acc, full_state, overlay[1],
                 lambda s, w: prog.edge_value(s, w, None), prog.reduce)
         return prog.apply(local_state, acc, arrays)
-    if route is not None:
-        gath = pull_gather_part_routed(arrays, full_state, local_state,
-                                       route[0], route[1], interpret)
-    else:
-        gath = pull_gather_part(arrays, full_state, local_state)
-    acc = pull_reduce_part(
-        prog, arrays, gath, method,
-        del_val=overlay[1].del_val if overlay is not None else None)
-    if overlay is not None:
-        from lux_tpu.mutate import overlay as _ovl
-
-        acc = _ovl.delta_scatter(
-            acc, full_state, overlay[1],
-            lambda s, w: prog.edge_value(s, w, None), prog.reduce)
-    return prog.apply(local_state, acc, arrays)
 
 
 def init_state(prog: PullProgram, arrays: ShardArrays) -> jnp.ndarray:
@@ -387,6 +390,7 @@ def run_pull_fixed(
     base gathers unchanged, tombstones neutralized, the fixed-capacity
     insert buffer folded in per iteration — occupancy is data, so
     churn never recompiles (luxaudit LUX-J1 pins it).
+    Span ``pull.dispatch`` around the call; counter ``pull.iterations``.
     Returns the final stacked (P, V, ...) state.
     """
     method = methods.resolve_sum(method, prog.reduce)
@@ -401,9 +405,11 @@ def run_pull_fixed(
     if tel is not None:
         tel = jax.tree.map(jnp.asarray, tel)
     fn = _pull_fixed_jit_donate if donate else _pull_fixed_jit
-    return fn(prog, spec, num_iters, method, arrays, state0, tel,
-              route_static=rs, route_arrays=ra,
-              interpret=_route_interpret(), ostatic=os_, oarrays=oa)
+    obs.count("pull.iterations", num_iters)
+    with obs.span("pull.dispatch"):
+        return fn(prog, spec, num_iters, method, arrays, state0, tel,
+                  route_static=rs, route_arrays=ra,
+                  interpret=_route_interpret(), ostatic=os_, oarrays=oa)
 
 
 def run_pull_fixed_overlapped(

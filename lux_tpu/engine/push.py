@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from lux_tpu import obs
 from lux_tpu.engine import methods
 from lux_tpu.graph.push_shards import PushArrays, PushShards, PushSpec, SRC_SENTINEL
 from lux_tpu.graph.shards import ShardArrays, ShardSpec
@@ -297,6 +298,15 @@ def edges_total(edges) -> int:
     return int((hi << np.uint64(32)) | lo)
 
 
+def _count_rounds(out: PushCarry) -> None:
+    """One call's rounds, dense rounds and walked arcs (``ne`` per dense
+    round plus the sparse rounds' frontier out-edges), from the final
+    carry, as recorder counters that do not sync."""
+    obs.count("push.rounds", out.it)
+    obs.count("push.dense_rounds", out.dense_rounds)
+    obs.count("push.arcs_walked", out.edges, edges_total)
+
+
 def _init_carry(prog, pspec, arrays):
     """Initial state + frontier queues (stacked (P, ...) layout)."""
     state0 = jax.vmap(prog.init_state)(
@@ -320,19 +330,20 @@ def _push_prep(pspec: PushSpec, spec: ShardSpec, parrays, c: PushCarry):
     out-edge walk (vmap over parts); decide the global direction.  Returns
     (q_vids_all, q_vals_all, (rows, counts, incl, totals) stacked (P, ...),
     use_dense)."""
-    P_ = spec.num_parts
-    q_vids_all = c.q_vid.reshape(P_ * pspec.f_cap)
-    q_vals_all = c.q_val.reshape(P_ * pspec.f_cap)
-    preps = jax.vmap(lambda parr: sparse_prep(parr, q_vids_all))(parrays)
-    totals = preps[3]
-    overflow = jnp.any(c.count > pspec.f_cap)
-    edge_overflow = totals.max() > pspec.e_sp
-    use_dense = (
-        (jnp.sum(c.count) > spec.nv // pspec.pull_threshold_den)
-        | overflow
-        | edge_overflow
-    )
-    return q_vids_all, q_vals_all, preps, use_dense
+    with jax.named_scope("push.plan"):
+        P_ = spec.num_parts
+        q_vids_all = c.q_vid.reshape(P_ * pspec.f_cap)
+        q_vals_all = c.q_val.reshape(P_ * pspec.f_cap)
+        preps = jax.vmap(lambda parr: sparse_prep(parr, q_vids_all))(parrays)
+        totals = preps[3]
+        overflow = jnp.any(c.count > pspec.f_cap)
+        edge_overflow = totals.max() > pspec.e_sp
+        use_dense = (
+            (jnp.sum(c.count) > spec.nv // pspec.pull_threshold_den)
+            | overflow
+            | edge_overflow
+        )
+        return q_vids_all, q_vals_all, preps, use_dense
 
 
 def _push_relax(prog, pspec: PushSpec, spec: ShardSpec, method, arrays,
@@ -369,6 +380,7 @@ def _push_relax(prog, pspec: PushSpec, spec: ShardSpec, method, arrays,
             "loop(..., oarrays=oarr)")
     dv = oarrays.del_val if ostatic is not None else None
 
+    @jax.named_scope("push.dense")
     def dense_all():
         if route_static is not None:
             return jax.vmap(
@@ -384,6 +396,7 @@ def _push_relax(prog, pspec: PushSpec, spec: ShardSpec, method, arrays,
                 del_val=o[0] if o else None)
         )(arrays, c.state, *((dv,) if dv is not None else ()))
 
+    @jax.named_scope("push.sparse")
     def sparse_all():
         step = sparse_part_step if merge == "bulk" else sparse_part_step_tree
 
@@ -430,19 +443,20 @@ def _push_requeue(prog, pspec: PushSpec, spec: ShardSpec, arrays,
                   c: PushCarry, new, preps, use_dense) -> PushCarry:
     """UPDATE phase: rebuild the frontier queues from changed vertices and
     account traversed edges."""
-    changed = (new != c.state) & arrays.vtx_mask
-    q_vid, q_val, cnt = jax.vmap(partial(build_queue, pspec))(
-        arrays, changed, new
-    )
-    active = jnp.sum(cnt)
-    # traversal accounting (SURVEY.md §6): dense walks every real edge,
-    # sparse walks the frontier's out-edges (the preps totals)
-    edges = _acc_edges(c.edges, spec.ne, preps[3].sum(), use_dense)
-    sp_work, dense_rounds = _acc_load(c, preps[3], use_dense)
-    return PushCarry(
-        new, q_vid, q_val, cnt, c.it + 1, active, edges, sp_work,
-        dense_rounds,
-    )
+    with jax.named_scope("push.requeue"):
+        changed = (new != c.state) & arrays.vtx_mask
+        q_vid, q_val, cnt = jax.vmap(partial(build_queue, pspec))(
+            arrays, changed, new
+        )
+        active = jnp.sum(cnt)
+        # traversal accounting (SURVEY.md §6): dense walks every real
+        # edge, sparse walks the frontier's out-edges (the preps totals)
+        edges = _acc_edges(c.edges, spec.ne, preps[3].sum(), use_dense)
+        sp_work, dense_rounds = _acc_load(c, preps[3], use_dense)
+        return PushCarry(
+            new, q_vid, q_val, cnt, c.it + 1, active, edges, sp_work,
+            dense_rounds,
+        )
 
 
 def _push_iteration(prog, pspec: PushSpec, spec: ShardSpec, method,
@@ -683,32 +697,43 @@ def run_push(
     ``merge`` ("bulk" | "tree", None = the banked ``tpu:merge_mode``)
     selects the sparse rounds' cross-part merge — bitwise-identical for
     the min/max push programs (ops/merge_tree.py).
+    Spans ``push.init`` (placement, initial carry) and ``push.dispatch``
+    (loop lookup and call); counters ``push.rounds``,
+    ``push.dense_rounds`` and ``push.arcs_walked`` (``_count_rounds``,
+    never a sync).
     Returns (final stacked state, iters, edge counter[, ring]).
     """
     method = methods.resolve_sum(method, prog.reduce)
     spec, pspec = shards.spec, shards.pspec
-    arrays = jax.tree.map(jnp.asarray, shards.arrays)
-    parrays = jax.tree.map(jnp.asarray, shards.parrays)
-    carry0 = _init_carry(prog, pspec, arrays)
-    tel = telemetry
-    if tel is not None:
-        tel = jax.tree.map(jnp.asarray, tel)
-    extra = () if tel is None else (tel,)
-    if route is None:
-        loop = compile_push_chunk(prog, pspec, spec, method, donate=donate,
-                                  telemetry=tel is not None, merge=merge)
-        out = loop(arrays, parrays, carry0, jnp.int32(max_iters), *extra)
-    else:
-        rs, ra = route
-        ra = jax.tree.map(jnp.asarray, ra)
-        loop = compile_push_chunk_routed(prog, pspec, spec, rs, method,
-                                         donate=donate,
-                                         telemetry=tel is not None,
-                                         merge=merge)
-        out = loop(arrays, parrays, carry0, jnp.int32(max_iters), *extra,
-                   route_arrays=ra)
+    with obs.span("push.init"):
+        arrays = jax.tree.map(jnp.asarray, shards.arrays)
+        parrays = jax.tree.map(jnp.asarray, shards.parrays)
+        carry0 = _init_carry(prog, pspec, arrays)
+        tel = telemetry
+        if tel is not None:
+            tel = jax.tree.map(jnp.asarray, tel)
+        extra = () if tel is None else (tel,)
+    with obs.span("push.dispatch"):
+        if route is None:
+            loop = compile_push_chunk(prog, pspec, spec, method,
+                                      donate=donate,
+                                      telemetry=tel is not None, merge=merge)
+            out = loop(arrays, parrays, carry0, jnp.int32(max_iters),
+                       *extra)
+        else:
+            rs, ra = route
+            ra = jax.tree.map(jnp.asarray, ra)
+            loop = compile_push_chunk_routed(prog, pspec, spec, rs, method,
+                                             donate=donate,
+                                             telemetry=tel is not None,
+                                             merge=merge)
+            out = loop(arrays, parrays, carry0, jnp.int32(max_iters),
+                       *extra, route_arrays=ra)
+    ring = None
     if tel is not None:
         out, ring = out
+    _count_rounds(out)
+    if ring is not None:
         return out.state, out.it, out.edges, ring
     return out.state, out.it, out.edges
 
@@ -737,46 +762,48 @@ def _spmd_push_prep(pspec: PushSpec, spec: ShardSpec, parr_blk,
     device then holds the full queue in a per-device ROTATED part order,
     which every downstream consumer absorbs (walk totals are sums, the
     destination scatter is min/max: order-independent, bitwise)."""
-    if merge == "tree" and num_dev > 1:
-        # unconditional straight-line collectives with static offsets —
-        # the LUX-J3 deadlock-freedom argument (ops/merge_tree.py)
-        q_vids_all = merge_tree.staged_concat_gather(
-            c.q_vid, PARTS_AXIS, num_dev
-        ).reshape(-1)
-        q_vals_all = merge_tree.staged_concat_gather(
-            c.q_val, PARTS_AXIS, num_dev
-        ).reshape(-1)
-    else:
-        # device order x resident order == global part order
-        # (shard_stacked gives device d parts [d*k, (d+1)*k)), so the
-        # tiled gather flattens straight into the (P * f_cap,) global
-        # queue view
-        q_vids_all = jax.lax.all_gather(
-            c.q_vid, PARTS_AXIS, tiled=True
-        ).reshape(-1)
-        q_vals_all = jax.lax.all_gather(
-            c.q_val, PARTS_AXIS, tiled=True
-        ).reshape(-1)
-    rows, counts, incl, totals = jax.vmap(
-        lambda parr: sparse_prep(parr, q_vids_all)
-    )(parr_blk)
-    g_cnt = jax.lax.psum(jnp.sum(c.count), PARTS_AXIS)
-    flags = jax.lax.psum(
-        jnp.stack(
-            [
-                jnp.sum((c.count > pspec.f_cap).astype(jnp.int32)),
-                jnp.sum((totals > pspec.e_sp).astype(jnp.int32)),
-                # tier vote: any part too big for the small buffer?
-                jnp.sum((totals > pspec.e_sp_small).astype(jnp.int32)),
-            ]
-        ),
-        PARTS_AXIS,
-    )
-    use_dense = (
-        (g_cnt > spec.nv // pspec.pull_threshold_den)
-        | (flags[:2].max() > 0)
-    )
-    return q_vids_all, q_vals_all, (rows, counts, incl, totals), use_dense, flags
+    with jax.named_scope("push.plan"):
+        if merge == "tree" and num_dev > 1:
+            # unconditional straight-line collectives with static offsets —
+            # the LUX-J3 deadlock-freedom argument (ops/merge_tree.py)
+            q_vids_all = merge_tree.staged_concat_gather(
+                c.q_vid, PARTS_AXIS, num_dev
+            ).reshape(-1)
+            q_vals_all = merge_tree.staged_concat_gather(
+                c.q_val, PARTS_AXIS, num_dev
+            ).reshape(-1)
+        else:
+            # device order x resident order == global part order
+            # (shard_stacked gives device d parts [d*k, (d+1)*k)), so the
+            # tiled gather flattens straight into the (P * f_cap,) global
+            # queue view
+            q_vids_all = jax.lax.all_gather(
+                c.q_vid, PARTS_AXIS, tiled=True
+            ).reshape(-1)
+            q_vals_all = jax.lax.all_gather(
+                c.q_val, PARTS_AXIS, tiled=True
+            ).reshape(-1)
+        rows, counts, incl, totals = jax.vmap(
+            lambda parr: sparse_prep(parr, q_vids_all)
+        )(parr_blk)
+        g_cnt = jax.lax.psum(jnp.sum(c.count), PARTS_AXIS)
+        flags = jax.lax.psum(
+            jnp.stack(
+                [
+                    jnp.sum((c.count > pspec.f_cap).astype(jnp.int32)),
+                    jnp.sum((totals > pspec.e_sp).astype(jnp.int32)),
+                    # tier vote: any part too big for the small buffer?
+                    jnp.sum((totals > pspec.e_sp_small).astype(jnp.int32)),
+                ]
+            ),
+            PARTS_AXIS,
+        )
+        use_dense = (
+            (g_cnt > spec.nv // pspec.pull_threshold_den)
+            | (flags[:2].max() > 0)
+        )
+        return (q_vids_all, q_vals_all, (rows, counts, incl, totals),
+                use_dense, flags)
 
 
 def _spmd_push_relax(prog, pspec: PushSpec, spec: ShardSpec, parr_blk,
@@ -789,6 +816,11 @@ def _spmd_push_relax(prog, pspec: PushSpec, spec: ShardSpec, parr_blk,
     local = c.state  # (k, V)
     V = spec.nv_pad
 
+    @jax.named_scope("push.dense")
+    def dense_branch():
+        return dense_fn(local)
+
+    @jax.named_scope("push.sparse")
     def sparse_branch():
         step = sparse_part_step if merge == "bulk" else sparse_part_step_tree
 
@@ -814,7 +846,7 @@ def _spmd_push_relax(prog, pspec: PushSpec, spec: ShardSpec, parr_blk,
             lambda: run(pspec.e_sp),
         )
 
-    return jax.lax.cond(use_dense, lambda: dense_fn(local), sparse_branch)
+    return jax.lax.cond(use_dense, dense_branch, sparse_branch)
 
 
 def _spmd_push_requeue(prog, pspec: PushSpec, spec: ShardSpec, qarr_blk,
@@ -823,20 +855,22 @@ def _spmd_push_requeue(prog, pspec: PushSpec, spec: ShardSpec, qarr_blk,
     queues from changed vertices, psum the global active count, and
     account traversed edges."""
     (_, _, (_, _, _, totals), use_dense, _) = plan
-    changed = (new != c.state) & qarr_blk.vtx_mask
-    q_vid, q_val, cnt = jax.vmap(partial(build_queue, pspec))(
-        qarr_blk, changed, new
-    )
-    active = jax.lax.psum(jnp.sum(cnt), PARTS_AXIS)
-    # uint32 psum is exact: a sparse round's global total is bounded by
-    # sum_p e_sp_p ≈ ne/4 < 2^32 (bigger frontiers force dense)
-    g_total = jax.lax.psum(jnp.sum(totals.astype(jnp.uint32)), PARTS_AXIS)
-    edges = _acc_edges(c.edges, spec.ne, g_total, use_dense)
-    sp_work, dense_rounds = _acc_load(c, totals, use_dense)
-    return PushCarry(
-        new, q_vid, q_val, cnt, c.it + 1, active, edges, sp_work,
-        dense_rounds,
-    )
+    with jax.named_scope("push.requeue"):
+        changed = (new != c.state) & qarr_blk.vtx_mask
+        q_vid, q_val, cnt = jax.vmap(partial(build_queue, pspec))(
+            qarr_blk, changed, new
+        )
+        active = jax.lax.psum(jnp.sum(cnt), PARTS_AXIS)
+        # uint32 psum is exact: a sparse round's global total is bounded
+        # by sum_p e_sp_p ≈ ne/4 < 2^32 (bigger frontiers force dense)
+        g_total = jax.lax.psum(jnp.sum(totals.astype(jnp.uint32)),
+                               PARTS_AXIS)
+        edges = _acc_edges(c.edges, spec.ne, g_total, use_dense)
+        sp_work, dense_rounds = _acc_load(c, totals, use_dense)
+        return PushCarry(
+            new, q_vid, q_val, cnt, c.it + 1, active, edges, sp_work,
+            dense_rounds,
+        )
 
 
 def _spmd_push_iter(prog, pspec: PushSpec, spec: ShardSpec, parr_blk,
@@ -1199,22 +1233,26 @@ def run_push_ring(
     assert method in ("scan", "scatter"), (
         segment.BUCKETED_METHODS_NOTE
     )
-    rarrays, parrays, view, carry0 = ring_init_dist(prog, shards, mesh)
-    if route is None:
-        run = _compile_push_ring(
-            prog, mesh, pspec, spec, shards.e_bucket_pad, method,
-            merge=merge,
-        )
-        out = run(rarrays, parrays, view, carry0, jnp.int32(max_iters))
-    else:
-        from lux_tpu.parallel.mesh import routed_run_args
+    with obs.span("push.init"):
+        rarrays, parrays, view, carry0 = ring_init_dist(prog, shards, mesh)
+    with obs.span("push.dispatch"):
+        if route is None:
+            run = _compile_push_ring(
+                prog, mesh, pspec, spec, shards.e_bucket_pad, method,
+                merge=merge,
+            )
+            out = run(rarrays, parrays, view, carry0, jnp.int32(max_iters))
+        else:
+            from lux_tpu.parallel.mesh import routed_run_args
 
-        rs, ra, interp = routed_run_args(mesh, route)
-        run = _compile_push_ring(
-            prog, mesh, pspec, spec, shards.e_bucket_pad, method,
-            route_static=rs, interpret=interp, merge=merge,
-        )
-        out = run(rarrays, parrays, view, carry0, jnp.int32(max_iters), ra)
+            rs, ra, interp = routed_run_args(mesh, route)
+            run = _compile_push_ring(
+                prog, mesh, pspec, spec, shards.e_bucket_pad, method,
+                route_static=rs, interpret=interp, merge=merge,
+            )
+            out = run(rarrays, parrays, view, carry0, jnp.int32(max_iters),
+                      ra)
+    _count_rounds(out)
     return out.state, out.it, out.edges
 
 
@@ -1238,20 +1276,23 @@ def run_push_dist(
     merge = _resolve_merge(merge)
     spec, pspec = shards.spec, shards.pspec
     assert spec.num_parts % mesh.devices.size == 0
-    arrays, parrays, carry0 = push_init_dist(prog, shards, mesh)
-    if route is None:
-        run = _compile_push_dist(prog, mesh, pspec, spec, method,
-                                 merge=merge)
-        out = run(arrays, parrays, carry0, jnp.int32(max_iters))
-    else:
-        from lux_tpu.engine.pull import _route_interpret
-        from lux_tpu.parallel.mesh import shard_stacked
+    with obs.span("push.init"):
+        arrays, parrays, carry0 = push_init_dist(prog, shards, mesh)
+    with obs.span("push.dispatch"):
+        if route is None:
+            run = _compile_push_dist(prog, mesh, pspec, spec, method,
+                                     merge=merge)
+            out = run(arrays, parrays, carry0, jnp.int32(max_iters))
+        else:
+            from lux_tpu.engine.pull import _route_interpret
+            from lux_tpu.parallel.mesh import shard_stacked
 
-        rs, ra = route
-        ra = shard_stacked(mesh, jax.tree.map(jnp.asarray, ra))
-        run = _compile_push_dist(prog, mesh, pspec, spec, method,
-                                 route_static=rs,
-                                 interpret=_route_interpret(),
-                                 merge=merge)
-        out = run(arrays, parrays, carry0, jnp.int32(max_iters), ra)
+            rs, ra = route
+            ra = shard_stacked(mesh, jax.tree.map(jnp.asarray, ra))
+            run = _compile_push_dist(prog, mesh, pspec, spec, method,
+                                     route_static=rs,
+                                     interpret=_route_interpret(),
+                                     merge=merge)
+            out = run(arrays, parrays, carry0, jnp.int32(max_iters), ra)
+    _count_rounds(out)
     return out.state, out.it, out.edges

@@ -43,7 +43,7 @@ def read_lux(path: str, weighted: Optional[bool] = None, mmap: bool = True) -> H
     """
     from lux_tpu import obs
 
-    with obs.span("graph.load", file=os.path.basename(path),
+    with obs.span("graph.read", file=os.path.basename(path),
                   mmap=mmap) as sp:
         g = _read_lux_impl(path, weighted, mmap)
         sp.set(nv=g.nv, ne=g.ne, weighted=g.weights is not None)

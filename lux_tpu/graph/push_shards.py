@@ -29,6 +29,7 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
+from lux_tpu import obs
 from lux_tpu.graph.csc import HostGraph
 from lux_tpu.graph.shards import (
     LANE,
@@ -97,100 +98,102 @@ def build_push_shards(
     sort_segments: bool = False,
     compact_gather: bool = False,
 ) -> PushShards:
-    # sort_segments: gather-locality relayout of the embedded pull
-    # layout — the push engine's DENSE rounds gather full[src_pos]
-    # exactly like the pull engine (min/max relaxation is order-free,
-    # so this is bitwise-invariant for the frontier apps).
-    # compact_gather: dense rounds gather through the unique-in-source
-    # mirror instead (engine/push.dense_part_step)
-    pull = build_pull_shards(
-        g, num_parts, cuts=cuts, sort_segments=sort_segments,
-        compact_gather=compact_gather,
-    )
-    spec = pull.spec
-    P, e_pad, nv_pad = num_parts, spec.e_pad, spec.nv_pad
-    cuts = pull.cuts
-
-    csr_dst_local = np.full((P, e_pad), nv_pad, np.int32)
-    csr_weight = np.zeros((P, e_pad), np.float32)
-    # native hot path: per-part counting sort by source, O(E + U log U)
-    # writing the dst/weight rows in place (lux_io.lux_push_part_build);
-    # the NumPy argsort path below is the fallback and the oracle
-    from lux_tpu import native
-
-    use_native = native.get_lib() is not None and (
-        g.weights is None or np.can_cast(g.weights.dtype, np.int32)
-    )
-    counts_scratch = np.zeros(g.nv, np.uint32) if use_native else None
-
-    uniq_all, rp_all = [], []
-    for p in range(P):
-        vlo, vhi = int(cuts[p]), int(cuts[p + 1])
-        elo, ehi = int(g.row_ptr[vlo]), int(g.row_ptr[vhi])
-        srcs = g.col_idx[elo:ehi]
-        if use_native:
-            uniq, rp = native.push_part_build(
-                srcs, g.row_ptr[vlo : vhi + 1],
-                g.weights[elo:ehi] if g.weights is not None else None,
-                g.nv, counts_scratch, csr_dst_local[p, : ehi - elo],
-                csr_weight[p, : ehi - elo] if g.weights is not None
-                else None,
-            )
-            uniq_all.append(uniq)
-            rp_all.append(rp)
-            continue
-        order = np.argsort(srcs, kind="stable")
-        s_sorted = srcs[order]
-        uniq, counts = (
-            np.unique(s_sorted, return_counts=True)
-            if len(s_sorted)
-            else (np.array([], np.int32), np.array([], np.int64))
+    # the span covers the embedded pull layout's own graph.shards
+    with obs.span("graph.shards", kind="push", parts=num_parts):
+        # sort_segments: gather-locality relayout of the embedded pull
+        # layout — the push engine's DENSE rounds gather full[src_pos]
+        # exactly like the pull engine (min/max relaxation is order-free,
+        # so this is bitwise-invariant for the frontier apps).
+        # compact_gather: dense rounds gather through the unique-in-source
+        # mirror instead (engine/push.dense_part_step)
+        pull = build_pull_shards(
+            g, num_parts, cuts=cuts, sort_segments=sort_segments,
+            compact_gather=compact_gather,
         )
-        rp = np.zeros(len(uniq) + 1, np.int64)
-        np.cumsum(counts, out=rp[1:])
-        uniq_all.append(uniq.astype(np.int32))
-        rp_all.append(rp.astype(np.int32))
-        # part-local dst per edge straight from the row_ptr slice — no
-        # global O(ne) dst_of_edges materialization (mmap-friendly)
-        dl_slice = np.repeat(
-            np.arange(vhi - vlo, dtype=np.int32),
-            np.diff(np.asarray(g.row_ptr[vlo : vhi + 1])).astype(np.int64),
+        spec = pull.spec
+        P, e_pad, nv_pad = num_parts, spec.e_pad, spec.nv_pad
+        cuts = pull.cuts
+
+        csr_dst_local = np.full((P, e_pad), nv_pad, np.int32)
+        csr_weight = np.zeros((P, e_pad), np.float32)
+        # native hot path: per-part counting sort by source, O(E + U log U)
+        # writing the dst/weight rows in place (lux_io.lux_push_part_build);
+        # the NumPy argsort path below is the fallback and the oracle
+        from lux_tpu import native
+
+        use_native = native.get_lib() is not None and (
+            g.weights is None or np.can_cast(g.weights.dtype, np.int32)
         )
-        csr_dst_local[p, : ehi - elo] = dl_slice[order]
-        if g.weights is not None:
-            csr_weight[p, : ehi - elo] = (
-                g.weights[elo:ehi][order].astype(np.float32)
+        counts_scratch = np.zeros(g.nv, np.uint32) if use_native else None
+
+        uniq_all, rp_all = [], []
+        for p in range(P):
+            vlo, vhi = int(cuts[p]), int(cuts[p + 1])
+            elo, ehi = int(g.row_ptr[vlo]), int(g.row_ptr[vhi])
+            srcs = g.col_idx[elo:ehi]
+            if use_native:
+                uniq, rp = native.push_part_build(
+                    srcs, g.row_ptr[vlo : vhi + 1],
+                    g.weights[elo:ehi] if g.weights is not None else None,
+                    g.nv, counts_scratch, csr_dst_local[p, : ehi - elo],
+                    csr_weight[p, : ehi - elo] if g.weights is not None
+                    else None,
+                )
+                uniq_all.append(uniq)
+                rp_all.append(rp)
+                continue
+            order = np.argsort(srcs, kind="stable")
+            s_sorted = srcs[order]
+            uniq, counts = (
+                np.unique(s_sorted, return_counts=True)
+                if len(s_sorted)
+                else (np.array([], np.int32), np.array([], np.int64))
             )
+            rp = np.zeros(len(uniq) + 1, np.int64)
+            np.cumsum(counts, out=rp[1:])
+            uniq_all.append(uniq.astype(np.int32))
+            rp_all.append(rp.astype(np.int32))
+            # part-local dst per edge straight from the row_ptr slice — no
+            # global O(ne) dst_of_edges materialization (mmap-friendly)
+            dl_slice = np.repeat(
+                np.arange(vhi - vlo, dtype=np.int32),
+                np.diff(np.asarray(g.row_ptr[vlo : vhi + 1])).astype(np.int64),
+            )
+            csr_dst_local[p, : ehi - elo] = dl_slice[order]
+            if g.weights is not None:
+                csr_weight[p, : ehi - elo] = (
+                    g.weights[elo:ehi][order].astype(np.float32)
+                )
 
-    u_pad = max(LANE, _round_up(max(len(u) for u in uniq_all) or 1, LANE))
-    uniq_src = np.full((P, u_pad), SRC_SENTINEL, np.int32)
-    csr_row_ptr = np.zeros((P, u_pad + 1), np.int32)
-    for p in range(P):
-        u, rp = uniq_all[p], rp_all[p]
-        uniq_src[p, : len(u)] = u
-        csr_row_ptr[p, : len(rp)] = rp
-        csr_row_ptr[p, len(rp) :] = rp[-1] if len(rp) else 0
+        u_pad = max(LANE, _round_up(max(len(u) for u in uniq_all) or 1, LANE))
+        uniq_src = np.full((P, u_pad), SRC_SENTINEL, np.int32)
+        csr_row_ptr = np.zeros((P, u_pad + 1), np.int32)
+        for p in range(P):
+            u, rp = uniq_all[p], rp_all[p]
+            uniq_src[p, : len(u)] = u
+            csr_row_ptr[p, : len(rp)] = rp
+            csr_row_ptr[p, len(rp) :] = rp[-1] if len(rp) else 0
 
-    if f_cap is None:
-        # queue sized like the reference: part vertices / SPARSE_THRESHOLD
-        # + slack (core/push_model.inl:393-397)
-        f_cap = _round_up(nv_pad // 16 + 128, LANE)
-    if e_sp is None:
-        e_sp = _round_up(max(e_pad // 4, LANE) + LANE, LANE)
-    # small tier = e_sp/16 (same ratio as the frontier threshold); only
-    # worth a second compiled branch when it actually shrinks the walk
-    e_sp_small = _round_up(max(int(e_sp) // 16, LANE), LANE)
-    if e_sp_small >= int(e_sp):
-        e_sp_small = 0
+        if f_cap is None:
+            # queue sized like the reference: part vertices / SPARSE_THRESHOLD
+            # + slack (core/push_model.inl:393-397)
+            f_cap = _round_up(nv_pad // 16 + 128, LANE)
+        if e_sp is None:
+            e_sp = _round_up(max(e_pad // 4, LANE) + LANE, LANE)
+        # small tier = e_sp/16 (same ratio as the frontier threshold); only
+        # worth a second compiled branch when it actually shrinks the walk
+        e_sp_small = _round_up(max(int(e_sp) // 16, LANE), LANE)
+        if e_sp_small >= int(e_sp):
+            e_sp_small = 0
 
-    pspec = PushSpec(
-        u_pad=u_pad, f_cap=int(f_cap), e_sp=int(e_sp),
-        e_sp_small=e_sp_small,
-    )
-    parrays = PushArrays(
-        uniq_src=uniq_src,
-        csr_row_ptr=csr_row_ptr,
-        csr_dst_local=csr_dst_local,
-        csr_weight=csr_weight,
-    )
-    return PushShards(pull=pull, pspec=pspec, parrays=parrays)
+        pspec = PushSpec(
+            u_pad=u_pad, f_cap=int(f_cap), e_sp=int(e_sp),
+            e_sp_small=e_sp_small,
+        )
+        parrays = PushArrays(
+            uniq_src=uniq_src,
+            csr_row_ptr=csr_row_ptr,
+            csr_dst_local=csr_dst_local,
+            csr_weight=csr_weight,
+        )
+        return PushShards(pull=pull, pspec=pspec, parrays=parrays)

@@ -33,6 +33,7 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
+from lux_tpu import obs
 from lux_tpu.graph.csc import HostGraph
 from lux_tpu.graph.partition import edge_balanced_cuts
 
@@ -331,30 +332,31 @@ def build_pull_shards(
     work instead of static in-degree.  ``sort_segments`` applies the
     gather-locality relayout (sort_segments_inplace); ``compact_gather``
     attaches the unique-in-source mirror (build_compact_mirror)."""
-    cuts, nv_pad, e_pad = shard_geometry(g.row_ptr, num_parts, g.nv, cuts)
-    if degrees is None:
-        degrees = g.out_degrees()
-    arrays = alloc_arrays(num_parts, nv_pad, e_pad)
-    for p in range(num_parts):
-        vlo, vhi = int(cuts[p]), int(cuts[p + 1])
-        elo, ehi = int(g.row_ptr[vlo]), int(g.row_ptr[vhi])
-        fill_part(
-            arrays, p, vlo, vhi,
-            g.row_ptr[vlo : vhi + 1] - elo,
-            g.col_idx[elo:ehi],
-            None if g.weights is None else g.weights[elo:ehi],
-            cuts, nv_pad, g.nv, degrees[vlo:vhi],
+    with obs.span("graph.shards", kind="pull", parts=num_parts):
+        cuts, nv_pad, e_pad = shard_geometry(g.row_ptr, num_parts, g.nv, cuts)
+        if degrees is None:
+            degrees = g.out_degrees()
+        arrays = alloc_arrays(num_parts, nv_pad, e_pad)
+        for p in range(num_parts):
+            vlo, vhi = int(cuts[p]), int(cuts[p + 1])
+            elo, ehi = int(g.row_ptr[vlo]), int(g.row_ptr[vhi])
+            fill_part(
+                arrays, p, vlo, vhi,
+                g.row_ptr[vlo : vhi + 1] - elo,
+                g.col_idx[elo:ehi],
+                None if g.weights is None else g.weights[elo:ehi],
+                cuts, nv_pad, g.nv, degrees[vlo:vhi],
+            )
+        if sort_segments:
+            sort_segments_inplace(arrays)
+        if compact_gather:
+            arrays = build_compact_mirror(arrays)
+        spec = ShardSpec(
+            num_parts=num_parts,
+            nv=g.nv,
+            ne=g.ne,
+            nv_pad=nv_pad,
+            e_pad=e_pad,
+            weighted=g.weights is not None,
         )
-    if sort_segments:
-        sort_segments_inplace(arrays)
-    if compact_gather:
-        arrays = build_compact_mirror(arrays)
-    spec = ShardSpec(
-        num_parts=num_parts,
-        nv=g.nv,
-        ne=g.ne,
-        nv_pad=nv_pad,
-        e_pad=e_pad,
-        weighted=g.weights is not None,
-    )
-    return PullShards(spec=spec, arrays=arrays, cuts=cuts)
+        return PullShards(spec=spec, arrays=arrays, cuts=cuts)

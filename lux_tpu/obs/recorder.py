@@ -21,6 +21,13 @@ Design constraints, in order:
   ON DEVICE (lux_tpu.obs.ring) and the recorder only sees the single
   end-of-run fetch.
 
+Once jax is imported, every span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a captured profiler
+trace shows the program's spans on its host plane, on the device ops'
+clock.  Counters (``count``) take host numbers or device scalars; a
+device scalar is held unsynced and read back, in one ``device_get`` for
+all pending values, when the counters are read.
+
 Event vocabulary (one JSON object per line):
 
   {"e":"m", "run":..,"pid":..,"wall":..,"mono":..,"argv":[..]}   file meta
@@ -70,6 +77,8 @@ DEFAULT_KEEP = 64
 #: never sweep a dir whose newest file was written in the last hour —
 #: a live run beyond the keep horizon must not lose its log mid-write
 SWEEP_MIN_AGE_S = 3600.0
+#: device values a recorder holds unread before it reads them all back
+PENDING_MAX = 1024
 
 
 def default_root() -> str:
@@ -162,7 +171,7 @@ class Span:
     """
 
     __slots__ = ("_rec", "name", "sid", "parent", "attrs", "_end_attrs",
-                 "t0", "dur", "ok")
+                 "t0", "dur", "ok", "_annotation")
 
     def __init__(self, rec: "Recorder", name: str, attrs: dict):
         self._rec = rec
@@ -174,6 +183,7 @@ class Span:
         self.t0 = 0.0
         self.dur = 0.0
         self.ok = True
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         self._end_attrs.update(attrs)
@@ -181,10 +191,19 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._rec._begin(self)
+        # the profiler's own host span, only where jax is already loaded
+        # (the recorder never imports it)
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.ok = exc_type is None
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         self._rec._end(self)
         return False
 
@@ -222,6 +241,11 @@ class Recorder:
         #: plan_build_seconds AND the bench ``phases`` dict (no drift:
         #: both are views over the same span durations)
         self._totals: dict[str, list] = {}
+        #: counter name -> [calls, total]
+        self._counters: dict[str, list] = {}
+        #: (name, value, convert) of counts whose value is still on the
+        #: device
+        self._pending: list = []
         self.log_path: Optional[str] = None
 
     # -- file plumbing --------------------------------------------------
@@ -429,6 +453,64 @@ class Recorder:
                 if k.startswith(prefix):
                     del self._totals[k]
 
+    # -- counters -------------------------------------------------------
+
+    def count(self, name: str, value=1, convert=None) -> None:
+        """Add ``value`` to counter ``name`` (one call).  A Python number
+        adds at once; anything else (a device scalar) is held unsynced
+        and read back with every other pending value when the counters
+        are read, or once :data:`PENDING_MAX` values wait.  ``convert``
+        maps the value read back to the number added."""
+        if convert is None and isinstance(value, (int, float)):
+            with self._lock:
+                self._add(name, value)
+            return
+        with self._lock:
+            self._pending.append((name, value, convert))
+            full = len(self._pending) >= PENDING_MAX
+        if full:
+            self._resolve()
+
+    def _add(self, name: str, value) -> None:
+        tot = self._counters.setdefault(name, [0, 0])
+        tot[0] += 1
+        tot[1] += value
+
+    def _resolve(self) -> None:
+        """Read every pending value back in one ``device_get``."""
+        with self._lock:
+            pending, self._pending = self._pending, []
+        if not pending:
+            return
+        values = [v for _, v, _ in pending]
+        jax = sys.modules.get("jax")
+        try:
+            if jax is not None:
+                values = jax.device_get(values)
+            added = [(name, convert(v) if convert else _number(v))
+                     for (name, _, convert), v in zip(pending, values)]
+        except (RuntimeError, TypeError, ValueError) as e:
+            # a deleted buffer or an odd value loses these counts, never
+            # the run
+            self.point("obs.count_lost", n=len(pending), error=repr(e))
+            return
+        with self._lock:
+            for name, v in added:
+                self._add(name, v)
+
+    def counters(self, prefix: str = "") -> dict:
+        """{name: (calls, total)} snapshot for names under prefix; reads
+        pending device values back first."""
+        self._resolve()
+        with self._lock:
+            return {k: (v[0], v[1]) for k, v in self._counters.items()
+                    if k.startswith(prefix)}
+
+
+def _number(v):
+    """A read-back scalar as a Python number."""
+    return v.item() if hasattr(v, "item") else v
+
 
 # ---------------------------------------------------------------------------
 # process-wide singleton + module-level convenience API
@@ -463,6 +545,14 @@ def span(name: str, **attrs) -> Span:
 
 def point(name: str, **attrs) -> None:
     recorder().point(name, **attrs)
+
+
+def count(name: str, value=1, convert=None) -> None:
+    recorder().count(name, value, convert)
+
+
+def counters(prefix: str = "") -> dict:
+    return recorder().counters(prefix)
 
 
 def run_id() -> str:

@@ -4,14 +4,10 @@ TPU-native replacement for the reference's observability hooks: Legion
 Prof/Spy exist behind -lg:* flags but are unused in-repo (SURVEY.md §5);
 the in-tree story is Realm::Clock timers.  Here: `jax.profiler` traces
 (viewable in XProf/Perfetto/TensorBoard) wrapping any run, plus
-`block_until_ready` fencing so phases attribute correctly.
-
-Round 6 (luxtrace): a captured trace no longer just sits in the profile
-dir — ``trace()`` parses it on exit (lux_tpu.obs.xprof, stdlib gzip+json)
-and writes the per-kernel device-time table into the run's event log, so
-``tools/luxview.py`` can answer "how much of the window ran inside the
-routed-pf ``fused_pass_gather`` kernels vs gathers/scatters/collectives"
-from the flight-recorder artifact alone.
+`block_until_ready` fencing so phases attribute correctly.  The
+engines name their phases (``jax.named_scope``: ``pull.gather``,
+``push.dense``, ...) and the recorder's spans land on the trace's host
+plane, so a captured trace attributes device time by phase.
 """
 from __future__ import annotations
 
@@ -25,9 +21,7 @@ log = logging.getLogger("lux_tpu")
 
 @contextlib.contextmanager
 def trace(trace_dir: str | None):
-    """Context manager: capture a jax.profiler trace when dir is given.
-    On exit the trace is parsed and the kernel-attribution table lands
-    in the event log (best-effort: attribution can never fail a run)."""
+    """Context manager: capture a jax.profiler trace when dir is given."""
     if not trace_dir:
         yield
         return
@@ -35,28 +29,12 @@ def trace(trace_dir: str | None):
 
     jax.profiler.start_trace(trace_dir)
     try:
-        with obs.span("xprof.trace", dir=trace_dir):
+        with obs.span("profiler.trace", dir=trace_dir):
             yield
     finally:
         jax.profiler.stop_trace()
-        rows = attribute_trace(trace_dir)
-        if rows:
-            top = ", ".join(f"{r['name'][:40]}={r['total_ms']}ms"
-                            for r in rows[:3])
-            log.info("profiler trace written to %s; top kernels: %s",
-                     trace_dir, top)
-        else:
-            log.info("profiler trace written to %s", trace_dir)
+        log.info("profiler trace written to %s", trace_dir)
         print(f"profiler trace written to {trace_dir}")
-
-
-def attribute_trace(trace_dir: str, top: int = 40):
-    """Parse an already-captured XProf/Perfetto bundle and emit the
-    per-kernel table into the event log; returns the rows (None when no
-    trace file was found).  Safe on any dir."""
-    from lux_tpu.obs import xprof
-
-    return xprof.emit_kernel_table(trace_dir, top=top)
 
 
 def annotate(name: str):

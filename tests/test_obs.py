@@ -1,12 +1,14 @@
 """luxtrace (lux_tpu.obs) tests: recorder span semantics + thread
 safety, on-device telemetry rings (bitwise no-op vs telemetry-off,
 donation, retrace/HBM neutrality), the LUX-O checker family, the
-luxview/obs_span CLIs on seeded event logs, the Prometheus dump, and
-XProf trace parsing."""
-import gzip
+luxview/obs_span CLIs on seeded event logs, the Prometheus dump,
+recorder counters, spans on the profiler's host plane, and the engines'
+named scopes."""
+import glob
 import importlib.util
 import json
 import os
+import re
 import threading
 import time
 
@@ -18,7 +20,6 @@ import jax.numpy as jnp
 
 from lux_tpu import obs
 from lux_tpu.obs import ring as obs_ring
-from lux_tpu.obs import xprof
 from lux_tpu.obs.recorder import Recorder
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -540,12 +541,6 @@ def _seed_event_log(tmp_path):
     r.point("telemetry.ring", kind="pull_fixed",
             cols=["it", "residual_l1"], n=3,
             rows=[[0, 0.5], [1, 0.25], [2, 0.125]], app="pagerank")
-    r.point("xprof.kernels", trace_dir="/tmp/x", rows=[
-        {"name": "fused_pass_gather_3", "class": "routed-pf",
-         "total_ms": 12.5, "calls": 30, "frac": 0.62},
-        {"name": "gather.17", "class": "gather", "total_ms": 7.5,
-         "calls": 10, "frac": 0.38}],
-        classes={"routed-pf": 12.5, "gather": 7.5})
     r.point("serve.metrics", completed=64, timeouts=0, rejected=1,
             batches=2, qps=880.0, latency_ms={"p50": 3.1, "p99": 9.7})
     r.point("bench.row", metric="pagerank_gteps_rmat18_1chip",
@@ -568,9 +563,8 @@ def test_luxview_golden_report(tmp_path, capsys):
     # waterfall: nesting + durations on the injected clock (1s ticks)
     assert "step.micro_race" in out and "compile.warm" in out
     assert "[timeout_s=300, rc=0]" in out
-    # telemetry curve, kernel table, serve, bench sections all render
+    # telemetry curve, serve, bench sections all render
     assert "ring: pull_fixed" in out and "residual_l1" in out
-    assert "fused_pass_gather_3" in out and "routed-pf" in out
     assert "qps=880.0" in out and "p99=9.7" in out
     assert "pagerank_gteps_rmat18_1chip" in out
     assert out.rstrip().endswith("run_id: golden")
@@ -693,103 +687,175 @@ def test_scheduler_periodic_snapshot(rec):
 
 
 # ---------------------------------------------------------------------------
-# xprof parsing
+# recorder counters
 # ---------------------------------------------------------------------------
 
 
-def _write_trace(tmp_path, events, gz=True):
-    d = os.path.join(str(tmp_path), "plugins", "profile", "run1")
-    os.makedirs(d, exist_ok=True)
-    doc = json.dumps({"traceEvents": events}).encode()
-    if gz:
-        with gzip.open(os.path.join(d, "host.trace.json.gz"), "wb") as f:
-            f.write(doc)
-    else:
-        with open(os.path.join(d, "host.trace.json"), "wb") as f:
-            f.write(doc)
-    return str(tmp_path)
+def test_count_calls_and_totals(rec):
+    obs.count("push.rounds", 3)
+    obs.count("push.rounds", 4)
+    obs.count("pull.iterations", 10)
+    assert obs.counters() == {"push.rounds": (2, 7),
+                              "pull.iterations": (1, 10)}
+    assert obs.counters("push.") == {"push.rounds": (2, 7)}
 
 
-def test_xprof_kernel_table_classifies_and_filters(tmp_path):
-    events = [
-        {"ph": "M", "name": "process_name", "pid": 7,
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "python host threads"}},
-        {"ph": "X", "pid": 7, "name": "fused_pass_gather_2", "dur": 3000},
-        {"ph": "X", "pid": 7, "name": "fused_pass_gather_2", "dur": 1000},
-        {"ph": "X", "pid": 7, "name": "gather.55", "dur": 2000},
-        {"ph": "X", "pid": 7, "name": "all-gather.1", "dur": 1000},
-        # host-pid event must be EXCLUDED (device lanes exist)
-        {"ph": "X", "pid": 1, "name": "hostloop", "dur": 99999},
-    ]
-    rows = xprof.kernel_table(_write_trace(tmp_path, events))
-    assert [r["name"] for r in rows] == ["fused_pass_gather_2",
-                                        "gather.55", "all-gather.1"]
-    top = rows[0]
-    assert top["class"] == "routed-pf" and top["calls"] == 2
-    assert top["total_ms"] == 4.0 and top["frac"] == 0.5714
-    assert xprof.class_summary(rows) == {
-        "routed-pf": 4.0, "gather": 2.0, "collective": 1.0}
+def test_count_device_scalar_resolved_at_read(rec):
+    x = jnp.int32(5)
+    obs.count("push.dense_rounds", x)
+    obs.count("push.arcs_walked", jnp.array([1, 2], jnp.uint32),
+              convert=lambda v: int(v[0]) << 32 | int(v[1]))
+    # held unread until the counters are read
+    assert len(rec._pending) == 2
+    got = obs.counters()
+    assert rec._pending == []
+    assert got["push.dense_rounds"] == (1, 5)
+    assert got["push.arcs_walked"] == (1, (1 << 32) + 2)
+    assert isinstance(got["push.dense_rounds"][1], int)
 
 
-def test_xprof_only_newest_capture_counts(tmp_path):
-    """A reused --profile-dir accumulates one plugins/profile/<ts> bundle
-    per start_trace; attribution must cover the newest only, never the
-    union of history."""
-    for run, name, dur, age in (("run_old", "stale.kernel", 9000, 100),
-                                ("run_new", "fresh.kernel", 1000, 0)):
-        d = os.path.join(str(tmp_path), "plugins", "profile", run)
-        os.makedirs(d)
-        p = os.path.join(d, "t.trace.json")
-        with open(p, "w") as f:
-            json.dump({"traceEvents": [
-                {"ph": "X", "pid": 1, "name": name, "dur": dur}]}, f)
-        old = time.time() - age
-        os.utime(p, (old, old))
-        os.utime(d, (old, old))
-    rows = xprof.kernel_table(str(tmp_path))
-    assert [r["name"] for r in rows] == ["fresh.kernel"]
+def test_count_pending_list_is_bounded(rec, monkeypatch):
+    monkeypatch.setattr(importlib.import_module("lux_tpu.obs.recorder"),
+                        "PENDING_MAX", 4)
+    for _ in range(3):
+        obs.count("push.rounds", jnp.int32(2))
+    assert len(rec._pending) == 3
+    obs.count("push.rounds", jnp.int32(2))  # the fourth reads all back
+    assert rec._pending == []
+    assert rec._counters["push.rounds"] == [4, 8]
 
 
-def test_xprof_host_file_excluded_when_device_lanes_exist(tmp_path):
-    """The all-pids fallback is bundle-wide: a host-only sibling file
-    must contribute nothing (and not flag the table host_only) when any
-    file in the bundle has device lanes."""
-    d = os.path.join(str(tmp_path), "plugins", "profile", "run1")
-    os.makedirs(d)
-    with open(os.path.join(d, "dev.trace.json"), "w") as f:
-        json.dump({"traceEvents": [
-            {"ph": "M", "name": "process_name", "pid": 7,
-             "args": {"name": "/device:TPU:0"}},
-            {"ph": "X", "pid": 7, "name": "gather.1", "dur": 2000}]}, f)
-    with open(os.path.join(d, "host.trace.json"), "w") as f:
-        json.dump({"traceEvents": [
-            {"ph": "X", "pid": 1, "name": "hostloop", "dur": 99999}]}, f)
-    meta = {}
-    rows = xprof.kernel_table(str(tmp_path), meta=meta)
-    assert [r["name"] for r in rows] == ["gather.1"]
-    assert "host_only" not in meta
+def test_counters_snapshot_difference(rec):
+    obs.count("push.rounds", jnp.int32(6))
+    before = obs.counters()
+    obs.count("push.rounds", jnp.int32(7))
+    obs.count("push.dense_rounds", 2)
+    after = obs.counters()
+    diff = {k: (c - before.get(k, (0, 0))[0], t - before.get(k, (0, 0))[1])
+            for k, (c, t) in after.items()}
+    assert diff == {"push.rounds": (1, 7), "push.dense_rounds": (1, 2)}
 
 
-def test_xprof_no_device_lane_falls_back_to_all(tmp_path):
-    events = [{"ph": "X", "pid": 1, "name": "scatter.9", "dur": 500}]
-    meta = {}
-    rows = xprof.kernel_table(_write_trace(tmp_path, events, gz=False),
-                              meta=meta)
-    assert len(rows) == 1 and rows[0]["class"] == "scatter"
-    # the fallback is LABELED: host wall time must not masquerade as
-    # device ms in the emitted event / luxview table
-    assert meta.get("host_only") is True
+def test_span_on_profiler_host_plane(rec, tmp_path):
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    with obs.span("push.init"):
+        jnp.arange(8).sum().block_until_ready()
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    host = [ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+    assert "push.init" in host
+    assert rec.total_count("push.init") == 1
 
 
-def test_xprof_emit_into_event_log(rec, tmp_path):
-    events = [{"ph": "X", "pid": 1, "name": "fusion.3", "dur": 1500}]
-    d = _write_trace(tmp_path, events)
-    rows = xprof.emit_kernel_table(d, top=5)
-    assert rows and rows[0]["class"] == "fusion"
-    (p,) = [e for e in read_events(rec.run_dir()) if e["e"] == "p"]
-    assert p["n"] == "xprof.kernels" and p["a"]["classes"] == {"fusion": 1.5}
-    assert p["a"]["host_only"] is True  # no device lanes in this capture
-    # empty dir: no rows, no event, no crash
-    assert xprof.emit_kernel_table(str(tmp_path / "empty")) is None
+def test_luxo_flags_count_in_traced_body(tmp_path):
+    src = (
+        "import jax\n"
+        "from lux_tpu import obs\n\n"
+        "@jax.jit\n"
+        "def f(x):\n"
+        "    obs.count('push.rounds', x)\n"
+        "    return x + 1\n\n"
+        "def host_side(x):\n"
+        "    y = f(x)\n"
+        "    obs.count('push.rounds', y)  # host side: legal\n"
+        "    return y\n"
+    )
+    findings = _luxo_run(tmp_path, src, "count.py")
+    assert [f.code for f in findings] == ["LUX-O002"]
+
+
+# ---------------------------------------------------------------------------
+# engine scopes and push counters
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def kron9():
+    from lux_tpu.graph import generate
+
+    return generate.rmat(9, 16, seed=23)
+
+
+@pytest.fixture(scope="module")
+def engine_hlo(kron9):
+    """Compiled HLO text of the pull loop (PageRank) and the push loop
+    (BFS), each holding its ops' op_name metadata."""
+    from lux_tpu.engine import methods, pull, push
+    from lux_tpu.graph.push_shards import build_push_shards
+    from lux_tpu.graph.shards import build_pull_shards
+    from lux_tpu.models.pagerank import PageRankProgram
+    from lux_tpu.models.sssp import SSSPProgram
+
+    g = kron9
+    pr = PageRankProgram(nv=g.nv)
+    psh = build_pull_shards(g, 1)
+    arrays = jax.tree.map(jnp.asarray, psh.arrays)
+    method = methods.resolve_sum("auto", pr.reduce)
+    pull_text = pull._pull_fixed_jit.lower(
+        pr, psh.spec, 3, method, arrays, pull.init_state(pr, arrays), None,
+        interpret=True).compile().as_text()
+    bfs = SSSPProgram(nv=g.nv, start=0)
+    sh = build_push_shards(g, 1)
+    arrays, parrays, carry0 = push.push_init(bfs, sh)
+    loop = push.compile_push_chunk(bfs, sh.pspec, sh.spec)
+    push_text = loop.lower(arrays, parrays, carry0,
+                           jnp.int32(100)).compile().as_text()
+    return {"pull": pull_text, "push": push_text}
+
+
+@pytest.mark.parametrize("scope", [
+    "pull.gather", "pull.reduce", "pull.apply",
+    "push.plan", "push.dense", "push.sparse", "push.requeue"])
+def test_engine_scope_in_op_names(engine_hlo, scope):
+    # a scope is one component of the op name, "vmap(...)"-wrapped
+    # where the engine vmaps over parts
+    text = engine_hlo[scope.split(".")[0]]
+    assert re.search(rf'op_name="[^"]*[/(]{re.escape(scope)}[)/]', text)
+
+
+def test_run_push_counters_match_carry(rec, kron9):
+    """run_push records the final carry's rounds, dense rounds and
+    walked arcs (ne per dense round plus each sparse round's frontier
+    out-edges), and its results are the loop's own, bit for bit."""
+    from lux_tpu.engine import push
+    from lux_tpu.graph.push_shards import build_push_shards
+    from lux_tpu.models.sssp import SSSPProgram
+
+    g = kron9
+    root = int(np.argmax(g.out_degrees()))
+    prog = SSSPProgram(nv=g.nv, start=root)
+    sh = build_push_shards(g, 1)
+
+    # the loop alone, with no recorder call
+    arrays, parrays, carry0 = push.push_init(prog, sh)
+    loop = push.compile_push_chunk(prog, sh.pspec, sh.spec)
+    want = loop(arrays, parrays, carry0, jnp.int32(10_000))
+
+    # the same rounds one by one, for the sparse rounds' walked arcs
+    load, comp, update = push.compile_push_phases(prog, sh.pspec, sh.spec)
+    arrays, parrays, c = push.push_init(prog, sh)
+    sparse_arcs, dense = 0, 0
+    while int(c.active) > 0:
+        plan = load(parrays, c)
+        if bool(plan[3]):
+            dense += 1
+        else:
+            sparse_arcs += int(plan[2][3].sum())
+        c = update(arrays, c, comp(arrays, parrays, c, plan), plan)
+
+    state, it, edges = push.run_push(prog, sh)
+    np.testing.assert_array_equal(np.asarray(state), np.asarray(want.state))
+    np.testing.assert_array_equal(np.asarray(edges), np.asarray(want.edges))
+    got = obs.counters("push.")
+    assert got["push.rounds"] == (1, int(want.it)) and int(it) == int(
+        want.it)
+    assert got["push.dense_rounds"] == (1, int(want.dense_rounds)) == (
+        1, dense)
+    assert dense >= 1 and sparse_arcs > 0  # both kinds of round ran
+    assert got["push.arcs_walked"] == (
+        1, g.ne * dense + sparse_arcs) == (1, push.edges_total(want.edges))

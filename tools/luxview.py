@@ -12,9 +12,8 @@ process — an aborted chip window's first question), the phase waterfall
 clock), distributed traces (the cross-process waterfalls luxstitch
 builds from the fleet's trace-context span attrs, skew-corrected, with
 fault injections interleaved), per-iteration telemetry curves (the
-on-device rings flushed at run end), the XProf kernel-attribution
-table, the last serving-metrics snapshot, and the bench rows that
-carried this run_id.
+on-device rings flushed at run end), the last serving-metrics
+snapshot, and the bench rows that carried this run_id.
 
 Pure stdlib and jax-free (the same bare-package stub as luxcheck): a
 post-mortem must render on a host whose jax install is in ANY state.  Reading is safe on live logs — unfinished spans simply
@@ -231,33 +230,6 @@ def render_rings(points, out: list) -> None:
         out.append("")
 
 
-def render_kernels(points, out: list) -> None:
-    ks = [p for p in points if p["name"] == "xprof.kernels"]
-    if not ks:
-        out.append("(no XProf kernel attribution in this log — pass a "
-                   "trace dir to utils.profiling.trace to capture one)")
-        return
-    a = ks[-1]["attrs"]
-    if a.get("host_only"):
-        out.append("NOTE: no device lanes in this capture — times below "
-                   "are HOST wall time (all pids), not device ms.")
-        out.append("")
-    classes = a.get("classes") or {}
-    if classes:
-        total = sum(classes.values()) or 1.0
-        out.append("class rollup (device ms):")
-        for cls, ms in sorted(classes.items(), key=lambda kv: -kv[1]):
-            out.append(f"  {cls:<12} {ms:10.3f} ms  "
-                       f"{100 * ms / total:5.1f}%")
-        out.append("")
-    out.append(f"{'kernel':<48} {'class':<11} {'ms':>10} {'calls':>6} "
-               f"{'frac':>6}")
-    for r in (a.get("rows") or [])[:25]:
-        out.append(f"{str(r.get('name', ''))[:48]:<48} "
-                   f"{r.get('class', ''):<11} {r.get('total_ms', 0):>10} "
-                   f"{r.get('calls', 0):>6} {r.get('frac', 0):>6}")
-
-
 def render_serve(points, out: list) -> None:
     snaps = [p for p in points if p["name"] == "serve.metrics"]
     if not snaps:
@@ -366,10 +338,6 @@ def render(metas, spans, points, bad, label: str, stitched=None) -> str:
     out.append("")
     render_rings(points, out)
     out.append("")
-    out.append("## Kernel attribution (XProf)")
-    out.append("")
-    render_kernels(points, out)
-    out.append("")
     out.append("## Serving metrics")
     out.append("")
     render_serve(points, out)
@@ -405,7 +373,7 @@ def resolve_target(target, root: str, latest: bool):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="render a luxtrace event log (flight-recorder "
-                    "post-mortem, waterfall, telemetry, kernels, serve)")
+                    "post-mortem, waterfall, telemetry, serve)")
     ap.add_argument("target", nargs="?",
                     help="run id, run dir, or events-*.jsonl file")
     ap.add_argument("--latest", action="store_true",
