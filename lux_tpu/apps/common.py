@@ -159,19 +159,20 @@ def downgrade_pf(cfg, layout: str) -> None:
 
 def _downgrade_scan_family(cfg: RunConfig, was_auto: bool, prog,
                            where: str) -> None:
-    """An AUTO-refined scan-family winner (mxsum/mxscan via the banked
-    ``tpu:sum`` entry, engine/methods.sum_mode) downgrades to the
-    blanket winner on the bucketed exchanges — their drivers run
-    scan/scatter only, exactly like segment_reduce_by_ends' own
-    downgrade.  An EXPLICIT --method choice is left for the branch's
-    own SystemExit (loud CLI failure, never a silent swap)."""
+    """An AUTO-resolved csc winner (mxscan, the TPU default of every
+    monoid, or a banked ``tpu:sum`` mxsum/mxscan; engine/methods
+    .resolve_sum) downgrades to the blanket winner on the bucketed
+    exchanges — their drivers run scan/scatter only, exactly like
+    segment_reduce_by_ends' own downgrade.  An EXPLICIT --method
+    choice is left for the branch's own SystemExit (loud CLI failure,
+    never a silent swap)."""
     if was_auto and cfg.method in ("mxsum", "mxscan"):
         import sys
 
         from lux_tpu.engine import methods
 
         blanket = methods.resolve("auto", prog.reduce)
-        print(f"# --method auto: banked {cfg.method} winner downgraded "
+        print(f"# --method auto: csc winner {cfg.method} downgraded "
               f"to {blanket} on {where} (bucketed reductions run "
               "scan/scatter)", file=sys.stderr)
         cfg.method = blanket
@@ -181,9 +182,9 @@ def validate_exchange(cfg: RunConfig, prog) -> None:
     """Reject incompatible --exchange combinations BEFORE the O(ne) shard
     build, with a CLI-level message (not a deep driver assert).  Resolves
     ``--method auto`` to the platform's measured winner first — through
-    ``resolve_sum``, so the banked ``tpu:sum`` scan-family winner
-    (ISSUE 11) actually reaches the engines from the CLI — and every
-    later check (and the run itself) sees a concrete strategy."""
+    ``resolve_sum``, so the csc engines' winner (mxscan on TPU) reaches
+    them from the CLI — and every later check (and the run itself) sees
+    a concrete strategy."""
     from lux_tpu.engine import methods
 
     was_auto = cfg.method == "auto"

@@ -205,7 +205,10 @@ def run_convergence_app(prog, shards, cfg, name: str, g=None):
     validation must use the returned layout, not the one passed in."""
     from lux_tpu.engine import methods
 
+    was_auto = cfg.method == "auto"
     cfg.method = methods.resolve_sum(cfg.method, prog.reduce)
+    if cfg.exchange == "ring":
+        common._downgrade_scan_family(cfg, was_auto, prog, "--exchange ring")
     common.resolve_route_auto(cfg)
     if (getattr(cfg, "route_gather", "") == "expand-pf"
             and cfg.exchange == "ring"):

@@ -11,18 +11,17 @@ the measured winners below.  Defaults follow measurements, not tradition:
     beats ``scan`` ~2x on the dominating comp phase — XLA:CPU lowers the
     sorted segment-sum to a tight sequential accumulation, while the
     log-depth associative scan makes multiple passes over the edge array.
-  * **TPU**: ``scatter``, because ``scan`` does not compile at the
-    sizes users run.  The 1-D ``lax.associative_scan`` ladder's compile
-    time grows with the edge count: for a described v5e the PageRank
-    loop took 38 s at rmat16, 156 s at rmat18 and 540 s at rmat20
-    (compile rehearsal, PR 21), while ``scatter`` compiles the rmat22
-    loop in 1.3 s.  ``mxscan`` runs PageRank at rmat18 on a v5e in
-    0.372 s against ``scatter``'s 0.669 s, 5.2e-7 off the f32 oracle
-    once its contraction keeps f32 exact (6.6e-4 off with the MXU's
-    default bf16 rounding) (PR 21 chip runs).  It cannot be a blanket
-    winner (it is invalid on the bucketed exchanges, which would then
-    resolve to ``scan``); a scan-family win is reached only through a
-    banked ``tpu:sum`` refinement of ``scan`` (``resolve_sum``).
+  * **TPU**: ``scatter`` as the blanket winner, because ``scan`` does
+    not compile at the sizes users run.  The 1-D
+    ``lax.associative_scan`` ladder's compile time grows with the edge
+    count: for a described v5e the PageRank loop took 38 s at rmat16,
+    156 s at rmat18 and 540 s at rmat20 (compiled for a described
+    v5e), while ``scatter`` compiles the rmat22 loop in 1.3 s.
+  * **TPU, csc engines**: ``mxscan`` (``CSC_WINNERS``, through
+    ``resolve_sum``), the blocked segmented-scan kernel: on one v5e
+    it reduces 2^27 arcs into 2^22 rows in 161 ms against
+    ``scatter``'s 1,173 ms (ops/pallas_scan).  It cannot be the blanket
+    winner: the bucketed exchanges run scan/scatter only.
 
 ``resolve`` is pure/host-side: it runs before any trace, so the concrete
 string participates in jit static arguments and compile caches as usual.
@@ -35,9 +34,9 @@ import threading
 #: Concrete strategies a resolution may produce.  ("cumsum"/"mxsum" are
 #: sum-only prefix-diff strategies and "pallas" needs the block-CSR
 #: layout — none is safe as a blanket default, so winners stay within
-#: the universally-valid {scan, scatter} set.  "mxscan" (ISSUE 11) is
-#: the blocked MXU segmented scan: valid on every csc path and on 1-D
-#: bucketed paths, reached through the scan-family refinement below.)
+#: the universally-valid {scan, scatter} set.  "mxscan" is the blocked
+#: segmented-scan kernel: valid on every csc path and on 1-D bucketed
+#: paths, the csc engines' TPU winner (CSC_WINNERS, resolve_sum).)
 CONCRETE = ("scan", "cumsum", "mxsum", "mxscan", "scatter")
 
 #: (platform, reduce) -> winner; a tpu row changes only with a chip
@@ -356,35 +355,43 @@ def record_sum_family_winner(winner: str) -> bool:
     return True
 
 
+#: (platform, reduce) -> winner on the csc gather-apply engines (pull
+#: single-device and dist, the push dense round), whose shards carry
+#: row_ptr and head flags: there the blocked segmented-scan kernel
+#: (ops/pallas_scan) applies to every monoid.  The bucketed exchanges
+#: keep the blanket ``resolve``, and (E, K) values leave the kernel in
+#: ops/segment.csc_method.  A row changes only with a chip measurement.
+CSC_WINNERS = {
+    ("tpu", "sum"): "mxscan",
+    ("tpu", "min"): "mxscan",
+    ("tpu", "max"): "mxscan",
+}
+
+
 def resolve_sum(method: str, reduce: str = "sum",
                 platform: str | None = None) -> str:
-    """``resolve`` plus the scan-family refinement for the csc
-    gather-apply engines (pull single-device + dist + the app CLIs;
-    push also routes through here, though every shipped push program
-    reduces with min/max, so the sum-only refinement is DORMANT there
-    until a sum-reduce push program exists): when an AUTO resolution
-    lands on the blanket "scan" default for a float SUM, the banked
-    ``tpu:sum`` scan-family winner (mxsum/mxscan) is followed instead.
-    Explicit concrete methods still pass through untouched, min/max
-    rows are untouched, and the bucketed ring/edge2d/feat DRIVERS keep
-    plain ``resolve`` (their scan/scatter asserts therefore never see
-    a refined winner; apps/common downgrades an auto-refined winner
-    before those exchanges, and ops/segment.segment_reduce_by_ends
-    downgrades for library callers who pass one explicitly).
+    """``resolve`` for the csc gather-apply engines (pull single-device
+    and dist, the push dense round, the app CLIs).  ``"auto"`` resolves,
+    in order, to: a set LUX_SUM_MODE (an explicit choice, float sums,
+    any platform); the banked ``tpu:sum`` scan-family winner (float
+    sums, on TPU); the ``CSC_WINNERS`` row for (platform, reduce);
+    else the blanket ``resolve``.  Explicit concrete methods pass
+    through untouched.
 
-    A set LUX_SUM_MODE is an EXPLICIT user choice: under
-    ``method="auto"`` it wins for float sums on every platform — even
-    where the blanket resolution is "scatter" (the CPU default) — so
-    'LUX_SUM_MODE forces a flavor anywhere' (docs/PERF.md) holds from
-    every driver.  A BANKED winner (no env) still refines only the
-    blanket "scan" default, on TPU."""
-    if (method == "auto" and reduce == "sum"
-            and os.environ.get("LUX_SUM_MODE")):
+    The bucketed ring/edge2d/feat/scatter drivers keep plain
+    ``resolve``: their scan/scatter asserts never see a csc winner
+    (apps/common downgrades one before those exchanges, and
+    ops/segment.segment_reduce_by_ends downgrades for library callers
+    who pass one explicitly)."""
+    if method != "auto":
+        return method
+    if reduce == "sum" and os.environ.get("LUX_SUM_MODE"):
         return sum_mode(platform)  # validates + returns the env choice
-    resolved = resolve(method, reduce, platform)
-    if method == "auto" and reduce == "sum" and resolved == "scan":
-        return sum_mode(platform)
-    return resolved
+    plat = platform if platform is not None else default_platform()
+    if (reduce == "sum" and plat == "tpu"
+            and _overlay_raw().get(SUM_MODE_KEY) in SUM_MODES):
+        return sum_mode(plat)
+    return CSC_WINNERS.get((plat, reduce)) or resolve(method, reduce, plat)
 
 
 #: CROSS-PART MERGE modes of the push engine's frontier aggregation

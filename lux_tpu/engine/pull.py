@@ -390,7 +390,9 @@ def run_pull_fixed(
     base gathers unchanged, tombstones neutralized, the fixed-capacity
     insert buffer folded in per iteration — occupancy is data, so
     churn never recompiles (luxaudit LUX-J1 pins it).
-    Span ``pull.dispatch`` around the call; counter ``pull.iterations``.
+    Span ``pull.dispatch`` around the call; counters ``pull.iterations``
+    and ``pull.mxscan_iterations`` (iterations whose reduce runs the
+    blocked scan kernel).
     Returns the final stacked (P, V, ...) state.
     """
     method = methods.resolve_sum(method, prog.reduce)
@@ -406,6 +408,11 @@ def run_pull_fixed(
         tel = jax.tree.map(jnp.asarray, tel)
     fn = _pull_fixed_jit_donate if donate else _pull_fixed_jit
     obs.count("pull.iterations", num_iters)
+    from lux_tpu.ops import expand
+
+    kernel = (not isinstance(rs, expand.FusedStatic)
+              and segment.csc_method(method, state0.ndim - 1) == "mxscan")
+    obs.count("pull.mxscan_iterations", num_iters if kernel else 0)
     with obs.span("pull.dispatch"):
         return fn(prog, spec, num_iters, method, arrays, state0, tel,
                   route_static=rs, route_arrays=ra,

@@ -298,12 +298,15 @@ def edges_total(edges) -> int:
     return int((hi << np.uint64(32)) | lo)
 
 
-def _count_rounds(out: PushCarry) -> None:
-    """One call's rounds, dense rounds and walked arcs (``ne`` per dense
-    round plus the sparse rounds' frontier out-edges), from the final
-    carry, as recorder counters that do not sync."""
+def _count_rounds(out: PushCarry, method: str) -> None:
+    """One call's rounds, dense rounds, dense rounds reduced by the
+    blocked scan kernel, and walked arcs (``ne`` per dense round plus
+    the sparse rounds' frontier out-edges), from the final carry, as
+    recorder counters that do not sync."""
     obs.count("push.rounds", out.it)
     obs.count("push.dense_rounds", out.dense_rounds)
+    kernel = segment.csc_method(method, out.state.ndim - 1) == "mxscan"
+    obs.count("push.mxscan_rounds", out.dense_rounds if kernel else 0)
     obs.count("push.arcs_walked", out.edges, edges_total)
 
 
@@ -699,8 +702,8 @@ def run_push(
     the min/max push programs (ops/merge_tree.py).
     Spans ``push.init`` (placement, initial carry) and ``push.dispatch``
     (loop lookup and call); counters ``push.rounds``,
-    ``push.dense_rounds`` and ``push.arcs_walked`` (``_count_rounds``,
-    never a sync).
+    ``push.dense_rounds``, ``push.mxscan_rounds`` and
+    ``push.arcs_walked`` (``_count_rounds``, never a sync).
     Returns (final stacked state, iters, edge counter[, ring]).
     """
     method = methods.resolve_sum(method, prog.reduce)
@@ -732,7 +735,7 @@ def run_push(
     ring = None
     if tel is not None:
         out, ring = out
-    _count_rounds(out)
+    _count_rounds(out, method)
     if ring is not None:
         return out.state, out.it, out.edges, ring
     return out.state, out.it, out.edges
@@ -1225,8 +1228,9 @@ def run_push_ring(
     (ops.expand.plan_ring_route_shards on the ring buckets) replays the
     dense rounds' streamed-block gathers as routed lane shuffles —
     bitwise-identical (note its plan-footprint SCALE NOTE: the routed
-    mode trades the O(nv/P) memory story for hot-loop speed)."""
-    method = methods.resolve_sum(method, prog.reduce)
+    mode trades the O(nv/P) memory story for hot-loop speed).  ``auto``
+    takes the blanket ``resolve``: the buckets carry no row_ptr."""
+    method = methods.resolve(method, prog.reduce)
     merge = _resolve_merge(merge)
     spec, pspec = shards.spec, shards.pspec
     assert spec.num_parts % mesh.devices.size == 0
@@ -1252,7 +1256,7 @@ def run_push_ring(
             )
             out = run(rarrays, parrays, view, carry0, jnp.int32(max_iters),
                       ra)
-    _count_rounds(out)
+    _count_rounds(out, method)
     return out.state, out.it, out.edges
 
 
@@ -1294,5 +1298,5 @@ def run_push_dist(
                                      interpret=_route_interpret(),
                                      merge=merge)
             out = run(arrays, parrays, carry0, jnp.int32(max_iters), ra)
-    _count_rounds(out)
+    _count_rounds(out, method)
     return out.state, out.it, out.edges

@@ -1,49 +1,50 @@
-"""mxscan: MXU-resident blocked segmented inclusive scan (ISSUE 11).
+"""mxscan: blocked segmented inclusive scan in one Pallas kernel.
 
-The float segmented sums of both engines bottom out in
-``lax.associative_scan`` on the VPU (ops/segment.py "scan") — a
-log-depth elementwise ladder whose every level re-materializes the full
-edge array.  arXiv:2505.15112's blocked systolic scan is the scan-side
-twin of mxreduce's one-hot reduction (arXiv:1811.09736, ISSUE 7): tile
-the array into 128-lane rows, compute each row's inclusive prefix as a
-triangular matmul on the MXU, and propagate one carried offset through
-the sequential grid.  Segment restarts fold into the SAME contraction
-by masking the triangular operand with the head flags, so
-``segment_*_csc`` consume the scanned array through the unchanged
-ends-gather — no separate correction pass.
+The segmented reductions of both engines need each segment's running
+value.  XLA's sorted scatter serializes on the TPU (1.17 s for 2^27
+arcs into 2^22 rows on a v5e), and the ``lax.associative_scan`` ladder
+(ops/segment.py "scan") re-materializes the edge array at every level
+and does not compile at scale.  This kernel makes one streaming pass:
+tile the array into 128-lane rows, scan each row under a segment mask,
+and carry one running value through the sequential grid.  The segment
+restarts fold into the mask, so ``segment_*_csc`` read the scanned
+array through the unchanged ends-gather.
 
 Mechanics, per (tile_rows, 128) VMEM tile of the flattened edge array
-(one Pallas kernel, one HBM read of the values + one write of the
-scanned array — the floor the VPU ladder's "2 sweeps" accounting only
-aspires to):
+(one HBM read of the values and the packed flag byte, one write of the
+scanned array):
 
   * the packed head/pad byte tile is split into head flags ``h`` and the
     padding mask, and the tile-wide inclusive head COUNT is one
-    ``(B, 128) x (128, 128)`` triangular matmul (counts <= 128: exact in
-    f32);
-  * per 128-lane row, the segmented-scan operand
-    ``M[i, j] = (j <= i) & (c[j] == c[i])`` is the triangular operand
-    masked by the head flags (equal inclusive head counts == same
-    segment; ``c`` is monotone, so the mask is two broadcast compares);
-  * float sums contract ``row @ M^T`` on the MXU — bf16 operands only
-    where exact (M is 0/1; bf16 values are already bf16; f32 values stay
-    f32), f32 accumulation ALWAYS, one rounding to the value dtype at
-    the tile write;
-  * min/max and INTEGER sums never touch the MXU: the same masked
-    layout reduces on the VPU, dtype-preserving and BITWISE equal to
-    the ladder scan (order-insensitive combiners);
-  * the inter-tile offset (the running value of the segment left open at
-    a tile boundary) lives in a (1, 1) VMEM scratch carried across the
-    sequential grid — reset at ``program_id(0) == 0``, so the kernel
-    vmaps over parts unchanged (the batch grid dim is prepended and the
-    tile axis keeps its program_id).
+    ``(B, 128) x (128, 128)`` triangular matmul (0/1 operands, counts
+    <= 128: exact in f32);
+  * per 128-lane row, ``Mt[j, i] = (j <= i) & (c[j] == c[i])`` marks the
+    slots of i's segment at or before i (equal inclusive head counts ==
+    same segment; ``c`` is monotone, so the mask is two broadcast
+    compares), and a masked reduce over j gives every slot's scanned
+    value on the VPU;
+  * float sums accumulate in f32 (bf16 values widened) and round once to
+    the value dtype at the row write; each segment's association is
+    fixed by the geometry, so results are deterministic and within the
+    f32 tolerance of the ladder.  min/max and integer sums are
+    dtype-preserving and BITWISE equal to the ladder scan
+    (order-insensitive combiners);
+  * the carried value (the running value of the segment left open at a
+    row or tile boundary) joins the slots before a row's first head and
+    lives in a (1, 1) VMEM scratch across the sequential grid — reset at
+    ``program_id(0) == 0``, so the kernel vmaps over parts unchanged
+    (the batch grid dim is prepended and the tile axis keeps its
+    program_id).
 
-Precision caveat (shared with mxreduce's contraction): the float-sum
-matmul multiplies EVERY in-row value by its 0/1 mask entry, so a
-non-finite value poisons its whole row (0 * Inf = NaN).  Padding slots
-are neutralized in-kernel via the packed pad bit; real values must be
-finite — true of every shipped sum program (pagerank ranks, CF errors).
-min/max keep Inf semantics exactly (masked select, no multiply).
+Padding slots are neutralized in-kernel via the packed pad bit, so junk
+there (Inf, NaN) never reaches a real output; a real value combines by
+select and add only, with the ladder's Inf/NaN semantics.
+
+Tile height, measured on one TPU v5e (2^27 f32 arcs into 2^22 rows,
+ends gather included, ms an iteration): 8 rows 226, 32 rows 176, 64 rows
+166, 128 rows 161, 256 rows 159 (compile 2.0 s at 128 rows against 4.9
+s at 256); XLA's scatter 1173.  The float sums' MXU contraction of the
+same mask read 240/182/173/168/150 and was dropped.
 """
 from __future__ import annotations
 
@@ -52,7 +53,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from lux_tpu.ops.pallas_shuffle import LANE, _exact, _mx_neutral
+from lux_tpu.ops.pallas_shuffle import LANE, _mx_neutral
 
 #: packed head/pad byte: bit 0 = segment head, bit 1 = padding slot
 HEAD_BIT = 1
@@ -60,14 +61,15 @@ PAD_BIT = 2
 
 
 def _mxscan_defaults(tile_rows=None) -> int:
-    """Scan-tile rows (LUX_MXSCAN_TILE_ROWS, default 8): rows per kernel
-    tile — the kernel unrolls one masked contraction per row, exactly
-    like mxreduce's LUX_MX_TILE_ROWS.  Read at TRACE time and baked into
-    the compiled program, never at replay."""
+    """Scan-tile rows (LUX_MXSCAN_TILE_ROWS, default 128, the chip's
+    choice in the module docstring): rows per kernel tile — the kernel
+    unrolls one masked reduce per row, exactly like mxreduce's
+    LUX_MX_TILE_ROWS.  Read at TRACE time and baked into the compiled
+    program, never at replay."""
     from lux_tpu.utils.config import env_int
 
     if tile_rows is None:
-        tile_rows = env_int("LUX_MXSCAN_TILE_ROWS", 8, minimum=1,
+        tile_rows = env_int("LUX_MXSCAN_TILE_ROWS", 128, minimum=1,
                             maximum=256)
     if tile_rows & (tile_rows - 1):
         raise ValueError(
@@ -77,70 +79,45 @@ def _mxscan_defaults(tile_rows=None) -> int:
 
 
 def _scan_kernel(op: str, tb: int, x_ref, hv_ref, o_ref, carry_ref):
-    """One (tb, 128) tile: masked triangular intra-row scan + the carried
-    inter-tile offset.  ``carry_ref`` is (1, 1) VMEM scratch holding the
-    scanned value at the end of the previous row/tile."""
+    """One (tb, 128) tile: masked intra-row scan + the carried value.
+    ``carry_ref`` is (1, 1) VMEM scratch in the accumulation dtype,
+    holding the scanned value at the end of the previous tile."""
     import jax.experimental.pallas as pl
 
-    i = pl.program_id(0)
-    x = x_ref[:]
-    float_sum = op == "sum" and jnp.issubdtype(x.dtype, jnp.floating)
-    neutral = _mx_neutral(op, x.dtype)
+    acc_dtype = carry_ref.dtype
+    neutral = _mx_neutral(op, acc_dtype)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _():
-        carry_ref[:, :] = jnp.full_like(
-            carry_ref, _mx_neutral(op, carry_ref.dtype))
+        carry_ref[:, :] = jnp.full_like(carry_ref, neutral)
 
     hv = hv_ref[:].astype(jnp.int32)
     h = (hv & HEAD_BIT).astype(jnp.float32)
-    pad = (hv & PAD_BIT) != 0
-    # neutralize padding BEFORE any contraction: junk pad values may be
-    # Inf/NaN and 0 * NaN = NaN would poison the row's matmul (the same
-    # rule as mxreduce's sentinel masking)
-    xm = jnp.where(pad, neutral, x)
+    # neutralize padding BEFORE the reduce: junk pad values may be
+    # Inf/NaN
+    xm = jnp.where((hv & PAD_BIT) != 0, neutral, x_ref[:].astype(acc_dtype))
     # inclusive head count per row: C[r, i] = sum_{j<=i} h[r, j] — ONE
     # (tb, 128) x (128, 128) triangular matmul, exact in f32 (<= 128)
     io0 = jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 0)
     io1 = jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 1)
-    tri_incl = (io0 <= io1).astype(jnp.float32)  # tri[j, i] = j <= i
     c = jax.lax.dot_general(
-        h, tri_incl, (((1,), (0,)), ((), ())),
+        h, (io0 <= io1).astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )  # (tb, 128): C[r, i] = sum_j h[r, j] * (j <= i)
-    ct = jnp.transpose(c)          # (128, tb): c[j] addressable per row
-    if not float_sum:
-        xt = jnp.transpose(xm)     # (128, tb): values as columns
-    cd = jnp.bfloat16 if x.dtype == jnp.bfloat16 else jnp.float32
+    )  # (tb, 128)
+    ct = jnp.transpose(c)    # (128, tb): c[j] addressable per row
+    xt = jnp.transpose(xm)   # (128, tb): values as columns
+    red = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}[op]
+    comb = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}[op]
     carry = carry_ref[:, :]
     for r in range(tb):
         c_row = c[r:r + 1, :]          # (1, 128): c[i] along lanes
         c_col = ct[:, r:r + 1]         # (128, 1): c[j] along sublanes
-        no_head = c_row == 0.0         # carry applies before row's 1st head
-        if float_sum:
-            # M[i, j] = (j <= i) & (c[j] >= c[i]): the triangular operand
-            # masked by the head flags (c monotone: >= on j <= i <=> ==)
-            m = (io1 <= io0) & (c_row >= c_col)
-            y = jax.lax.dot_general(
-                xm[r:r + 1, :].astype(cd), m.astype(cd),
-                (((1,), (1,)), ((), ())),
-                precision=_exact(cd),
-                preferred_element_type=jnp.float32,
-            )  # (1, 128): y[0, i] = sum_j x[j] * M[i, j], f32 accumulate
-            y = y + jnp.where(no_head, carry, jnp.float32(0.0))
-        else:
-            # masked VPU layout: Mt[j, i] = (j <= i) & (c[j] >= c[i])
-            mt = (io0 <= io1) & (c_col >= c_row)
-            masked = jnp.where(mt, xt[:, r:r + 1], neutral)
-            red = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}[op]
-            y = red(masked, axis=0, keepdims=True)  # (1, 128)
-            if op == "sum":
-                y = y + jnp.where(no_head, carry,
-                                  jnp.zeros((), x.dtype))
-            elif op == "min":
-                y = jnp.where(no_head, jnp.minimum(y, carry), y)
-            else:
-                y = jnp.where(no_head, jnp.maximum(y, carry), y)
+        # Mt[j, i] = (j <= i) & (c[j] >= c[i]) (c monotone: >= is ==)
+        mt = (io0 <= io1) & (c_col >= c_row)
+        y = red(jnp.where(mt, xt[:, r:r + 1], neutral), axis=0,
+                keepdims=True)         # (1, 128)
+        # the carried value joins the slots before the row's first head
+        y = jnp.where(c_row == 0.0, comb(y, carry), y)
         carry = y[:, LANE - 1:LANE]
         o_ref[r:r + 1, :] = y.astype(o_ref.dtype)
     carry_ref[:, :] = carry
@@ -160,23 +137,25 @@ def mxscan_segmented(vals, head_flag, invalid, op: str = "sum",
     padding outputs, and are never read by the ends gathers.
 
     Returns the scanned array in ``vals.dtype`` (float sums accumulate
-    in f32 and round once per tile row on the way out).
+    in f32 and round once per row on the way out).
     """
     if op not in ("sum", "min", "max"):
         raise ValueError(f"mxscan op must be sum|min|max, got {op!r}")
     if vals.ndim != 1:
         raise ValueError(
             "mxscan_segmented is a 1-D kernel; (E, K)-valued reductions "
-            "keep the VPU scan (ops/segment dispatches the fallback)")
+            "take another method (ops/segment.csc_method)")
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    tb = _mxscan_defaults(tile_rows)
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     n = vals.shape[0]
     if n == 0:
         return vals
+    # a short array takes the fewest rows that hold it, not a full tile
+    tb = min(_mxscan_defaults(tile_rows),
+             1 << (pl.cdiv(n, LANE) - 1).bit_length())
     hv = (head_flag.astype(jnp.uint8) * jnp.uint8(HEAD_BIT)  # luxcheck: disable=LUX-P003 -- flag BYTE (values 0-3), a mask operand never used as a gather index
           | invalid.astype(jnp.uint8) * jnp.uint8(PAD_BIT))  # luxcheck: disable=LUX-P003 -- same packed flag byte, second bit
     unit = tb * LANE
@@ -210,11 +189,11 @@ def mxscan_residency_bytes(tile_rows: int, val_bytes: int = 4) -> int:
     entry (analysis/ir/vmem.check_vmem_mxscan).  Streamed operands
     double-buffer through the Pallas pipeline: the value tile (in + out)
     and the packed head/pad byte tile; on top live the tile-wide head
-    count and its transpose (f32), the transposed value tile (VPU
-    path bound), the per-row (128, 128) masked triangular operand plus
-    its compare/select twin, and the (1, 1) carry."""
+    count and its transpose (f32), the transposed value tile (widened
+    to f32 for float sums), the per-row (128, 128) segment mask plus
+    its select twin, and the (1, 1) carry."""
     tile = 2 * tile_rows * LANE * (2 * val_bytes + 1)
     counts = 2 * tile_rows * LANE * 4          # C + C^T, f32
-    xt = tile_rows * LANE * val_bytes          # transposed values
+    xt = tile_rows * LANE * max(val_bytes, 4)  # transposed values
     masks = 2 * LANE * LANE * 4                # iota/compare + masked op
     return tile + counts + xt + masks + 8

@@ -10,7 +10,7 @@ atomics):
   * ``scan``    — segmented inclusive scan via `lax.associative_scan` over
                   (value, head_flag) pairs, then gather each segment's last
                   element.  Log-depth, fully vectorized, numerically safe
-                  (accumulation stays within a segment).  The default.
+                  (accumulation stays within a segment).
   * ``cumsum``  — plain cumsum + gather-diff at row boundaries (sum only).
                   Cheapest, but the global prefix magnitude costs float32
                   precision on large graphs.
@@ -30,9 +30,12 @@ atomics):
                   like mxsum vs scan — tolerance-equal to ``scan``);
                   min/max and integer sums use the same masked layout on
                   the VPU, BITWISE equal to ``scan``.  1-D values only:
-                  (E, K) shapes fall back to ``scan`` (bitwise-identical
-                  to asking for ``scan``).
+                  (E, K) shapes take ``scatter`` on TPU and ``scan``
+                  elsewhere (``csc_method``).  The csc engines' TPU
+                  default (engine/methods.CSC_WINNERS).
   * ``scatter`` — `segment_sum/min/max` with sorted ids (XLA scatter).
+                  The CPU default; on TPU, the default of the bucketed
+                  exchanges and of (E, K) values.
 
 All take static-shape padded inputs from lux_tpu.graph.shards.
 """
@@ -72,10 +75,22 @@ def _ends_gather(scanned, row_ptr, neutral):
 BUCKETED_METHODS_NOTE = (
     "bucketed (row_ptr-free) exchange drivers accept method='scan' "
     "or 'scatter' only (--method / LUX_BENCH_METHOD); auto-resolved "
-    "scan-family winners (LUX_SUM_MODE: mxsum/mxscan) never reach "
-    "this driver — they refine through resolve_sum on the csc "
+    "csc winners (mxscan on TPU, LUX_SUM_MODE's mxsum/mxscan) never "
+    "reach this driver — they resolve through resolve_sum on the csc "
     "engines, and apps/common downgrades them before these "
     "exchanges — so pass 'scan' or 'scatter' explicitly")
+
+
+def csc_method(method: str, ndim: int) -> str:
+    """The strategy a csc reducer runs for ``method`` on values of rank
+    ``ndim``.  The blocked kernel is 1-D, so (E, K) values asking for
+    ``mxscan`` take XLA's scatter on TPU, where the ladder scan does not
+    compile at scale (engine/methods), and the ladder scan elsewhere."""
+    if method != "mxscan" or ndim == 1:
+        return method
+    from lux_tpu.engine import methods
+
+    return "scatter" if methods.default_platform() == "tpu" else "scan"
 
 
 def _mxscan_csc(vals, row_ptr, head_flag, op):
@@ -150,8 +165,7 @@ def segment_sum_csc(
         # same family-downgrade contract as segment_reduce_by_ends's
         # (a banked tpu:sum=mxsum winner stays safe on every program)
         method = "scan"
-    if method == "mxscan" and vals.ndim > 1:
-        method = "scan"  # the blocked kernel is 1-D (module docstring)
+    method = csc_method(method, vals.ndim)
     if method == "mxscan":
         scanned = _mxscan_csc(vals, row_ptr, head_flag, "sum")
         return _ends_gather(scanned, row_ptr, jnp.zeros((), vals.dtype))
@@ -194,8 +208,7 @@ def _scatter_dtype(vals: jnp.ndarray) -> jnp.ndarray:
 
 
 def _segment_minmax(vals, row_ptr, head_flag, dst_local, op, neutral, method):
-    if method == "mxscan" and vals.ndim > 1:
-        method = "scan"  # the blocked kernel is 1-D (module docstring)
+    method = csc_method(method, vals.ndim)
     if method == "mxscan":
         scanned = _mxscan_csc(vals, row_ptr, head_flag,
                               "min" if op is jnp.minimum else "max")

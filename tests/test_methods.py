@@ -1,6 +1,7 @@
 """Platform-aware method resolution (engine.methods): defaults follow
 measured winners (PERF.md), explicit choices pass through untouched."""
 import numpy as np
+import pytest
 
 from lux_tpu.engine import methods
 
@@ -20,6 +21,55 @@ def test_measured_winners():
     assert methods.resolve("auto", "sum", "tpu") == "scatter"
     assert methods.resolve("auto", "min", "tpu") == "scatter"
     assert methods.resolve("auto", "max", "tpu") == "scatter"
+
+
+@pytest.mark.parametrize("reduce", ["sum", "min", "max"])
+def test_csc_auto_is_the_scan_kernel_on_tpu(reduce):
+    """The csc engines' TPU default is the blocked scan kernel for every
+    monoid (the chip race in ops/pallas_scan); the blanket default that
+    the bucketed exchanges take stays scatter."""
+    assert methods.resolve_sum("auto", reduce, "tpu") == "mxscan"
+    assert methods.resolve("auto", reduce, "tpu") == "scatter"
+
+
+@pytest.mark.parametrize("reduce", ["sum", "min", "max"])
+def test_csc_auto_on_cpu_stays_scatter(reduce):
+    assert methods.resolve_sum("auto", reduce, "cpu") == "scatter"
+    # explicit choices pass through on every platform
+    assert methods.resolve_sum("scan", reduce, "tpu") == "scan"
+    assert methods.resolve_sum("scatter", reduce, "tpu") == "scatter"
+
+
+def test_push_ring_auto_on_tpu_takes_blanket(monkeypatch):
+    """The bucketed push driver with method='auto' on TPU resolves to
+    the blanket scatter, never the csc kernel its assert refuses."""
+    from lux_tpu.engine import push
+    from lux_tpu.graph import generate
+    from lux_tpu.models.sssp import SSSPProgram, bfs_reference
+    from lux_tpu.parallel import mesh as mesh_lib, ring
+
+    monkeypatch.setenv("LUX_METHOD_PLATFORM", "tpu")
+    g = generate.rmat(8, 6, seed=98)
+    prs = ring.build_push_ring_shards(g, 4)
+    prog = SSSPProgram(nv=prs.spec.nv, start=0)
+    state, _, _ = push.run_push_ring(prog, prs, mesh_lib.make_mesh(4))
+    np.testing.assert_array_equal(
+        prs.scatter_to_global(np.asarray(state)), bfs_reference(g, 0))
+
+
+@pytest.mark.parametrize("app", ["sssp", "components"])
+def test_push_app_ring_auto_on_tpu_downgrades(monkeypatch, capsys, app):
+    """The push apps on --exchange ring with --method auto on TPU: the
+    csc winner (mxscan, a min/max monoid here) downgrades to the blanket
+    winner before the ring driver, with a note, and the run checks."""
+    import importlib
+
+    monkeypatch.setenv("LUX_METHOD_PLATFORM", "tpu")
+    mod = importlib.import_module(f"lux_tpu.apps.{app}")
+    res = mod.main(["--rmat-scale", "8", "-ng", "4", "--distributed",
+                    "--exchange", "ring", "-check"])
+    assert res == 0
+    assert "csc winner mxscan downgraded to scatter" in capsys.readouterr().err
 
 
 def test_unknown_platform_falls_back_portable():

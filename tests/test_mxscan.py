@@ -295,9 +295,10 @@ def test_csc_f32_exact_case_bitwise(rng):
 
 
 def test_csc_2d_falls_back_to_scan_bitwise(rng):
-    """(E, K) values: the blocked kernel is 1-D, so method='mxscan'
-    must produce EXACTLY the ladder scan's bits (the engine-safety
-    contract — a banked winner can never crash the CF/feat shapes)."""
+    """(E, K) values off TPU: the blocked kernel is 1-D, so
+    method='mxscan' must produce EXACTLY the ladder scan's bits (the
+    engine-safety contract — a csc winner can never crash the CF/feat
+    shapes)."""
     rp, head, dst, e_pad = _csc([10, 0, 25, 3], pad=4)
     vals = rng.standard_normal((e_pad, 5)).astype(np.float32)
     args = (jnp.asarray(vals), jnp.asarray(rp), jnp.asarray(head),
@@ -306,6 +307,103 @@ def test_csc_2d_falls_back_to_scan_bitwise(rng):
         ref = np.asarray(fn(*args, method="scan"))
         got = np.asarray(fn(*args, method="mxscan"))
         np.testing.assert_array_equal(ref, got)
+
+
+def test_csc_2d_on_tpu_takes_scatter(monkeypatch, rng):
+    """(E, K) values on TPU: method='mxscan' runs XLA's scatter (the
+    ladder scan does not compile at scale there), bit for bit, with no
+    kernel and no scan in the program."""
+    from lux_tpu.analysis.ir import aot
+
+    monkeypatch.setenv("LUX_METHOD_PLATFORM", "tpu")
+    assert segment.csc_method("mxscan", 2) == "scatter"
+    assert segment.csc_method("mxscan", 1) == "mxscan"
+    assert segment.csc_method("scan", 2) == "scan"
+    rp, head, dst, e_pad = _csc([10, 0, 25, 3], pad=4)
+    vals = rng.standard_normal((e_pad, 5)).astype(np.float32)
+    args = (jnp.asarray(vals), jnp.asarray(rp), jnp.asarray(head),
+            jnp.asarray(dst))
+    for fn in (segment.segment_sum_csc, segment.segment_min_csc,
+               segment.segment_max_csc):
+        ref = np.asarray(fn(*args, method="scatter"))
+        np.testing.assert_array_equal(
+            ref, np.asarray(fn(*args, method="mxscan")))
+        jaxpr = aot.traced_jaxpr(jax.jit(
+            lambda v, fn=fn: fn(v, *args[1:], method="mxscan")).trace(
+                args[0]))
+        assert aot.count_primitive(jaxpr, "pallas_call") == 0
+        assert aot.count_primitive(jaxpr, "cumsum") == 0
+        assert sum(aot.count_primitive(jaxpr, p) for p in (
+            "scatter-add", "scatter_add", "scatter-min", "scatter_min",
+            "scatter-max", "scatter_max")) == 1
+
+
+def test_colfilter_auto_on_tpu_is_scatter(monkeypatch, tmp_path):
+    """The CF engine on TPU with method='auto': the csc resolution says
+    mxscan, the (E, K) values run scatter — bitwise the explicit
+    scatter run — and no iteration counts as a kernel iteration."""
+    from lux_tpu import obs
+    from lux_tpu.graph import generate
+    from lux_tpu.models import colfilter as cf
+    from lux_tpu.obs.recorder import Recorder
+
+    monkeypatch.setenv("LUX_METHOD_PLATFORM", "tpu")
+    g = generate.rmat(7, 6, seed=11, weighted=True)
+    want = cf.colfilter(g, num_iters=3, gamma=1e-3, method="scatter")
+    rec = Recorder(run_id="cf", root=str(tmp_path), enabled=True)
+    old = obs.install(rec)
+    try:
+        got = cf.colfilter(g, num_iters=3, gamma=1e-3, method="auto")
+        counts = obs.counters("pull.")
+    finally:
+        rec.close()
+        obs.install(old)
+    np.testing.assert_array_equal(got, want)
+    assert counts["pull.iterations"] == (1, 3)
+    assert counts["pull.mxscan_iterations"] == (1, 0)
+
+
+@pytest.fixture(scope="module")
+def graph500_csc():
+    """A Graph500-shaped segment geometry at scale 12: the Kronecker
+    graph's in-degrees (empty rows included), one hub widened past two
+    default kernel tiles, and padding past row_ptr[-1]."""
+    from lux_tpu.graph import generate
+
+    g = generate.rmat(12, 16, seed=24)
+    widths = np.bincount(g.dst_of_edges(), minlength=g.nv)
+    hub = int(np.argmax(widths))
+    widths[hub] = 2 * PS._mxscan_defaults() * 128 + 7000
+    assert (widths == 0).sum() > 100
+    return (widths,) + _csc(widths, pad=300)
+
+
+@pytest.mark.parametrize("op", ["sum", "min"])
+def test_graph500_shaped_at_default_tile(graph500_csc, op, rng):
+    """At the default tile geometry (128 rows): f32 sums equal the
+    scatter's within the existing tolerance, int32 mins bitwise."""
+    widths, rp, head, dst, e_pad = graph500_csc
+    assert PS._mxscan_defaults() == 128
+    assert max(widths) > 2 * 128 * 128 and e_pad > rp[-1]
+    if op == "sum":
+        vals = rng.random(e_pad).astype(np.float32)
+        fn = segment.segment_sum_csc
+    else:
+        vals = rng.integers(0, 1 << 30, e_pad).astype(np.int32)
+        fn = segment.segment_min_csc
+    vals[rp[-1]:] = vals[0] if op == "min" else np.float32(np.nan)
+    args = (jnp.asarray(vals), jnp.asarray(rp), jnp.asarray(head),
+            jnp.asarray(dst))
+    ref = np.asarray(fn(*args, method="scatter"))
+    got = np.asarray(fn(*args, method="mxscan"))
+    assert got.dtype == vals.dtype
+    if op == "sum":
+        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            got, _seg_oracle(widths, vals[:rp[-1]], "sum", np.float32),
+            rtol=1e-5, atol=1e-5)
+    else:
+        np.testing.assert_array_equal(got, ref)
 
 
 @pytest.mark.parametrize("tile_rows", [1, 2, 32])
@@ -564,8 +662,9 @@ def test_sum_mode_follows_banked_winner_tpu_only(monkeypatch, tmp_path, scan_tpu
     assert methods.sum_mode("tpu") == "mxscan"
     assert methods.sum_mode("cpu") == "scan"
     assert methods.resolve_sum("auto", "sum", "tpu") == "mxscan"
-    # min/max rows and CPU rows untouched; explicit choice wins
-    assert methods.resolve_sum("auto", "min", "tpu") == "scan"
+    # min/max take the csc table's TPU row (the banked entry is
+    # sum-only), CPU rows are untouched, an explicit choice wins
+    assert methods.resolve_sum("auto", "min", "tpu") == "mxscan"
     assert methods.resolve_sum("auto", "sum", "cpu") == "scatter"
     assert methods.resolve_sum("scan", "sum", "tpu") == "scan"
     assert methods.resolve_sum("mxsum", "sum", "tpu") == "mxsum"
@@ -577,8 +676,8 @@ def test_sum_mode_follows_banked_winner_tpu_only(monkeypatch, tmp_path, scan_tpu
 
 def test_sum_mode_ignores_non_family_entries(monkeypatch, tmp_path, scan_tpu_rows):
     """tpu:sum may also hold the app-race's blanket winner ('scatter'):
-    sum_mode ignores it (resolve() already followed it) and a garbage
-    entry reads as the default."""
+    sum_mode ignores it (resolve() follows it, the csc engines keep
+    their table's row) and a garbage entry reads as the default."""
     import json
 
     f = tmp_path / "w.json"
@@ -586,11 +685,13 @@ def test_sum_mode_ignores_non_family_entries(monkeypatch, tmp_path, scan_tpu_row
     monkeypatch.setenv("LUX_METHOD_WINNERS", str(f))
     _reset_overlay_caches(monkeypatch)
     assert methods.sum_mode("tpu") == "scan"
-    assert methods.resolve_sum("auto", "sum", "tpu") == "scatter"
+    assert methods.resolve("auto", "sum", "tpu") == "scatter"
+    assert methods.resolve_sum("auto", "sum", "tpu") == "mxscan"
     f.write_text(json.dumps({"tpu:sum": "pallas"}))
     _reset_overlay_caches(monkeypatch)
     assert methods.sum_mode("tpu") == "scan"
-    assert methods.resolve_sum("auto", "sum", "tpu") == "scan"
+    assert methods.resolve("auto", "sum", "tpu") == "scan"
+    assert methods.resolve_sum("auto", "sum", "tpu") == "mxscan"
     _reset_overlay_caches(monkeypatch)
 
 

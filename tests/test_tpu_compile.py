@@ -8,6 +8,7 @@ this file loads the TPU library.  The persistent compile cache is off
 around these compiles, since a described device cannot read them back.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -155,3 +156,75 @@ def test_routed_pf_pagerank_loop_compiles(one_chip, route):
         state, None, route_static=rs, route_arrays=_on_chip(one_chip, ra),
         interpret=False, ostatic=None, oarrays=None).compile()
     assert _kernels(c) >= 1
+
+
+#: the benchmark cell's shape: Graph500 scale 22, 2^27 arcs on one part
+NV, NE = 1 << 22, 1 << 27
+
+
+def _cell_arrays(one_chip):
+    """ShardArrays shapes of one part holding the whole cell's graph."""
+    from lux_tpu.graph.shards import ShardArrays
+
+    return ShardArrays(
+        row_ptr=_sds(one_chip, (1, NV + 1), jnp.int32),
+        src_pos=_sds(one_chip, (1, NE), jnp.int32),
+        dst_local=_sds(one_chip, (1, NE), jnp.int32),
+        head_flag=_sds(one_chip, (1, NE), jnp.bool_),
+        edge_mask=_sds(one_chip, (1, NE), jnp.bool_),
+        vtx_mask=_sds(one_chip, (1, NV), jnp.bool_),
+        degree=_sds(one_chip, (1, NV), jnp.int32),
+        global_vid=_sds(one_chip, (1, NV), jnp.int32),
+        weights=_sds(one_chip, (1, NE), jnp.float32),
+        mirror_pos=_sds(one_chip, (1, 0), jnp.int32),
+        mirror_rel=_sds(one_chip, (1, NE), jnp.int32))
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Resolve methods and pick Pallas's compiled (not interpreted) path
+    as the engines do on a TPU host."""
+    monkeypatch.setenv("LUX_METHOD_PLATFORM", "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _no_scatter(text: str) -> bool:
+    return re.search(r"\bscatter\(", text) is None
+
+
+def test_pagerank_cell_loop_reduces_with_the_kernel(one_chip, as_on_tpu):
+    """The PageRank cell's 10-iteration loop with the TPU's auto method:
+    the segment reduce is the one scan kernel, and no scatter is left."""
+    from lux_tpu.engine import methods, pull
+    from lux_tpu.graph.shards import ShardSpec
+    from lux_tpu.models.pagerank import PageRankProgram
+
+    method = methods.resolve_sum("auto", "sum", "tpu")
+    assert method == "mxscan"
+    c = pull._pull_fixed_jit.lower(
+        PageRankProgram(nv=NV), ShardSpec(1, NV, NE, NV, NE, False), 10,
+        method, _cell_arrays(one_chip),
+        _sds(one_chip, (1, NV), jnp.float32), None, route_static=None,
+        route_arrays=None, interpret=False, ostatic=None,
+        oarrays=None).compile()
+    text = c.as_text()
+    assert _kernels(c) == 1
+    assert _no_scatter(text)
+
+
+def test_bfs_dense_step_reduces_with_the_kernel(one_chip, as_on_tpu):
+    """A push dense round (int32 min, the BFS cell's shapes) with the
+    TPU's auto method: one scan kernel, no scatter."""
+    from lux_tpu.engine import methods, push
+    from lux_tpu.models.sssp import SSSPProgram
+
+    prog = SSSPProgram(nv=NV, start=0)
+    method = methods.resolve_sum("auto", prog.reduce, "tpu")
+    assert method == "mxscan"
+    c = jax.jit(lambda arrays, full, local: jax.vmap(
+        lambda arr, loc: push.dense_part_step(prog, arr, full, loc, method)
+    )(arrays, local)).lower(
+        _cell_arrays(one_chip), _sds(one_chip, (NV,), jnp.int32),
+        _sds(one_chip, (1, NV), jnp.int32)).compile()
+    assert _kernels(c) == 1
+    assert _no_scatter(c.as_text())
