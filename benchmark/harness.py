@@ -189,13 +189,16 @@ def measure(args, t0: float, warm_up: bool = True) -> dict:
     if rehearsal:
         params["scale"] = args.cpu_rehearsal
     name = "graph500-s{scale}-ef{edge_factor}-undirected".format(**params)
+    if params["generator"] != "graph500 kronecker":
+        name += "-" + params["generator"].replace(" ", "-")
     graph, path, made = graph500.dataset(CACHE_DIR, name, params, args.seed)
     prep = app.prepare(conf, graph)
     sources = traffic.draw(mix, prep["eligible"])
     works = {src: app.work(conf, graph, prep, src) for src in sources}
     dataset_s = time.perf_counter() - d0
     _line(phase="dataset", name=name, seed=args.seed, nv=graph.nv,
-          ne=graph.ne, generated=made, seconds=dataset_s)
+          ne=graph.ne, generated=made, seconds=dataset_s,
+          maxrss_gb=graph500.maxrss_gb())
 
     spans, clock = Spans(), CompileClock()
     jax.monitoring.register_event_duration_secs_listener(clock)

@@ -74,3 +74,29 @@ def test_no_window_or_device_is_an_error():
     only_device = _TEXT.split("planes {\n  id: 2")[0]
     with pytest.raises(ValueError, match="window"):
         trace.summarize(ProfileData.from_text_proto(only_device))
+
+
+@pytest.mark.parametrize("op,holds_ops", [
+    ("%while.1 = (s32[]) while((s32[]) %t), body=%b", True),
+    ("%conditional.2 = f32[8]{0} conditional(pred[] %p, f32[8]{0} %x), "
+     "branch_computations={%t, %f}", True),
+    ("%call.3 = f32[8]{0} call(f32[8]{0} %x), to_apply=%g", True),
+    ("%custom-call.4 = f32[8]{0} custom-call(f32[8]{0} %x), "
+     "custom_call_target=\"tpu_custom_call\"", False),
+    ("%fusion.5 = f32[8]{0} fusion(f32[8]{0} %x), kind=kLoop, calls=%f",
+     False),
+])
+def test_containers(op, holds_ops):
+    assert bool(trace.CONTAINER.search(op)) is holds_ops
+
+
+def test_a_kernel_inside_a_loop_is_an_op():
+    """A Pallas kernel (``custom-call``) counts as an op, as the loop
+    around it does not."""
+    kernel = ('%custom-call.7 = f32[8]{0} custom-call(f32[8]{0} %x), '
+              'custom_call_target=\\"tpu_custom_call\\"')
+    s = trace.summarize(ProfileData.from_text_proto(
+        _TEXT.replace('name: "scatter.7"', f'name: "{kernel}"')))
+    assert s.busy_s == pytest.approx(4e-6)
+    assert s.ops == [("%custom-call.7 = f32[8]", pytest.approx(3e-6)),
+                     ("fusion.3", pytest.approx(1.5e-6))]

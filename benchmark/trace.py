@@ -22,8 +22,9 @@ WINDOW = "window"
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 #: ops that hold other ops (a loop, a branch, a call): their events span
-#: their children's, so they count for busy time but not as ops
-CONTAINER = re.compile(r"\b(while|conditional|call)\(")
+#: their children's, so they count for busy time but not as ops.  A
+#: ``custom-call(`` (a Pallas kernel) is an op.
+CONTAINER = re.compile(r"(?<![\w-])(while|conditional|call)\(")
 
 
 @dataclasses.dataclass
