@@ -21,7 +21,11 @@ from lux_tpu.utils.timing import IterStats, Timer, report_elapsed
 
 def build_push_app_shards(g, cfg):
     """Push shards for the selected dense-round --exchange strategy (or
-    the block-CSR layout when the dense rounds run the Pallas kernel)."""
+    the block-CSR layout when the dense rounds run the Pallas kernel).
+    With --distributed and the allgather exchange the shards come back
+    placed on the run's mesh, each device holding its own parts
+    (``push.place_push_shards``), and the host copies are dropped:
+    ``push.run_push`` then runs them on that mesh."""
     if cfg.sort_segments and (
         cfg.exchange != "allgather" or cfg.method == "pallas"
     ):
@@ -58,10 +62,15 @@ def build_push_app_shards(g, cfg):
         from lux_tpu.parallel.ring import build_push_ring_shards
 
         return build_push_ring_shards(g, cfg.num_parts)
-    return build_push_shards(
+    shards = build_push_shards(
         g, cfg.num_parts, sort_segments=cfg.sort_segments,
         compact_gather=cfg.compact_gather,
     )
+    if not cfg.distributed or cfg.edge_shards > 1:
+        return shards
+    from lux_tpu.engine import push
+
+    return push.place_push_shards(shards, common.make_mesh_if(cfg))
 
 
 def _save_frontier_ckpt(cfg, name, shards, carry):

@@ -213,6 +213,7 @@ def _compile_delta_dist(prog, mesh, pspec: PushSpec, spec: ShardSpec,
         mesh=mesh,
         in_specs=(arr_specs, parr_specs, carry_specs, P()),
         out_specs=carry_specs,
+        **methods.spmd_kw(method),
     )
     def run(arr_blk, parr_blk, c_blk, max_iters):
         def cond(c):

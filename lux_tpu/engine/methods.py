@@ -306,6 +306,15 @@ def cf_err_dot_mode() -> str:
 #: only through a banked on-chip measurement — never assumed.
 SUM_MODES = ("scan", "mxsum", "mxscan")
 
+
+def spmd_kw(method: str, routed: bool = False) -> dict:
+    """``jax.shard_map`` keywords for a distributed loop whose reduce
+    runs ``method``: a Pallas kernel's output (the ``mxscan`` scan, a
+    routed gather) declares no varying mesh axes, so the vma check is
+    off wherever one runs under the map."""
+    return {"check_vma": False} if routed or method == "mxscan" else {}
+
+
 #: overlay key the scan-family race banks its winner under.  The SAME
 #: key also carries the app-level bench race's blanket winner (which
 #: may be "scatter"): the two readers consume disjoint value domains —

@@ -33,6 +33,8 @@ deterministic, duplicates impossible.
 """
 from __future__ import annotations
 
+import math
+from dataclasses import replace
 from functools import lru_cache, partial
 from typing import Any, NamedTuple, Protocol
 
@@ -45,7 +47,12 @@ from lux_tpu.engine import methods
 from lux_tpu.graph.push_shards import PushArrays, PushShards, PushSpec, SRC_SENTINEL
 from lux_tpu.graph.shards import ShardArrays, ShardSpec
 from lux_tpu.ops import merge_tree, segment
-from lux_tpu.parallel.mesh import PARTS_AXIS, flatten_gather, shard_stacked
+from lux_tpu.parallel.mesh import (
+    PARTS_AXIS,
+    flatten_gather,
+    parts_mesh,
+    shard_stacked,
+)
 
 
 class PushProgram(Protocol):
@@ -298,16 +305,36 @@ def edges_total(edges) -> int:
     return int((hi << np.uint64(32)) | lo)
 
 
-def _count_rounds(out: PushCarry, method: str) -> None:
+def exchange_bytes(pspec: PushSpec, spec: ShardSpec, num_dev: int,
+                   state) -> tuple[int, int]:
+    """(bytes a round, further bytes a dense round) that one chip of a
+    ``num_dev``-device mesh receives over ICI: every round gathers the
+    other chips' (P - k) frontier queues of ``f_cap`` (int32 vid, value)
+    pairs; a dense round gathers their (P - k) ``nv_pad`` states too
+    (all-gathered or streamed round the ring).  A value is one vertex's
+    row of the stacked (P, nv_pad, ...) ``state``.  The scalar psums are
+    left out.  (0, 0) on one device."""
+    value = state.dtype.itemsize * math.prod(state.shape[2:])
+    other = spec.num_parts - spec.num_parts // num_dev
+    return (other * pspec.f_cap * (4 + value),
+            other * spec.nv_pad * value)
+
+
+def _count_rounds(out: PushCarry, method: str,
+                  exchange: tuple[int, int] = (0, 0)) -> None:
     """One call's rounds, dense rounds, dense rounds reduced by the
-    blocked scan kernel, and walked arcs (``ne`` per dense round plus
-    the sparse rounds' frontier out-edges), from the final carry, as
-    recorder counters that do not sync."""
+    blocked scan kernel, walked arcs (``ne`` per dense round plus the
+    sparse rounds' frontier out-edges) and the bytes one chip received
+    (``exchange``: ``exchange_bytes`` of the mesh), from the final
+    carry, as recorder counters that do not sync."""
     obs.count("push.rounds", out.it)
     obs.count("push.dense_rounds", out.dense_rounds)
     kernel = segment.csc_method(method, out.state.ndim - 1) == "mxscan"
     obs.count("push.mxscan_rounds", out.dense_rounds if kernel else 0)
     obs.count("push.arcs_walked", out.edges, edges_total)
+    per_round, per_dense = exchange
+    obs.count("push.exchange_bytes", (out.it, out.dense_rounds),
+              lambda v: per_round * int(v[0]) + per_dense * int(v[1]))
 
 
 def _init_carry(prog, pspec, arrays):
@@ -702,10 +729,27 @@ def run_push(
     the min/max push programs (ops/merge_tree.py).
     Spans ``push.init`` (placement, initial carry) and ``push.dispatch``
     (loop lookup and call); counters ``push.rounds``,
-    ``push.dense_rounds``, ``push.mxscan_rounds`` and
-    ``push.arcs_walked`` (``_count_rounds``, never a sync).
+    ``push.dense_rounds``, ``push.mxscan_rounds``, ``push.arcs_walked``
+    and ``push.exchange_bytes`` (0 here; ``_count_rounds``, never a
+    sync).
     Returns (final stacked state, iters, edge counter[, ring]).
+
+    Shards that ``place_push_shards`` laid over a mesh of more than one
+    device (``parts_mesh``, read from the arrays' sharding on the host)
+    run ``run_push_dist`` on that mesh; ``donate``, ``telemetry`` and
+    ``route`` belong to the single-device loop and are refused there.
+    Host (NumPy) shards and shards on one device take the single-device
+    loop as before.
     """
+    mesh = parts_mesh(shards.arrays)
+    if mesh is not None:
+        if donate or telemetry is not None or route is not None:
+            raise ValueError(
+                "run_push: shards placed over a multi-device mesh run the "
+                "distributed loop, which has no donate=, telemetry= or "
+                "route= (the single-device loop's options)")
+        return run_push_dist(prog, shards, mesh, max_iters, method,
+                             merge=merge)
     method = methods.resolve_sum(method, prog.reduce)
     spec, pspec = shards.spec, shards.pspec
     with obs.span("push.init"):
@@ -766,41 +810,42 @@ def _spmd_push_prep(pspec: PushSpec, spec: ShardSpec, parr_blk,
     which every downstream consumer absorbs (walk totals are sums, the
     destination scatter is min/max: order-independent, bitwise)."""
     with jax.named_scope("push.plan"):
-        if merge == "tree" and num_dev > 1:
-            # unconditional straight-line collectives with static offsets —
-            # the LUX-J3 deadlock-freedom argument (ops/merge_tree.py)
-            q_vids_all = merge_tree.staged_concat_gather(
-                c.q_vid, PARTS_AXIS, num_dev
-            ).reshape(-1)
-            q_vals_all = merge_tree.staged_concat_gather(
-                c.q_val, PARTS_AXIS, num_dev
-            ).reshape(-1)
-        else:
-            # device order x resident order == global part order
-            # (shard_stacked gives device d parts [d*k, (d+1)*k)), so the
-            # tiled gather flattens straight into the (P * f_cap,) global
-            # queue view
-            q_vids_all = jax.lax.all_gather(
-                c.q_vid, PARTS_AXIS, tiled=True
-            ).reshape(-1)
-            q_vals_all = jax.lax.all_gather(
-                c.q_val, PARTS_AXIS, tiled=True
-            ).reshape(-1)
+        with jax.named_scope("push.exchange"):
+            if merge == "tree" and num_dev > 1:
+                # unconditional straight-line collectives with static
+                # offsets — the LUX-J3 deadlock-freedom argument
+                # (ops/merge_tree.py)
+                q_vids_all = merge_tree.staged_concat_gather(
+                    c.q_vid, PARTS_AXIS, num_dev
+                ).reshape(-1)
+                q_vals_all = merge_tree.staged_concat_gather(
+                    c.q_val, PARTS_AXIS, num_dev
+                ).reshape(-1)
+            else:
+                # device order x resident order == global part order
+                # (shard_stacked gives device d parts [d*k, (d+1)*k)), so
+                # the tiled gather flattens straight into the (P * f_cap,)
+                # global queue view
+                q_vids_all = jax.lax.all_gather(
+                    c.q_vid, PARTS_AXIS, tiled=True
+                ).reshape(-1)
+                q_vals_all = jax.lax.all_gather(
+                    c.q_val, PARTS_AXIS, tiled=True
+                ).reshape(-1)
         rows, counts, incl, totals = jax.vmap(
             lambda parr: sparse_prep(parr, q_vids_all)
         )(parr_blk)
-        g_cnt = jax.lax.psum(jnp.sum(c.count), PARTS_AXIS)
-        flags = jax.lax.psum(
-            jnp.stack(
-                [
-                    jnp.sum((c.count > pspec.f_cap).astype(jnp.int32)),
-                    jnp.sum((totals > pspec.e_sp).astype(jnp.int32)),
-                    # tier vote: any part too big for the small buffer?
-                    jnp.sum((totals > pspec.e_sp_small).astype(jnp.int32)),
-                ]
-            ),
-            PARTS_AXIS,
+        votes = jnp.stack(
+            [
+                jnp.sum((c.count > pspec.f_cap).astype(jnp.int32)),
+                jnp.sum((totals > pspec.e_sp).astype(jnp.int32)),
+                # tier vote: any part too big for the small buffer?
+                jnp.sum((totals > pspec.e_sp_small).astype(jnp.int32)),
+            ]
         )
+        with jax.named_scope("push.exchange"):
+            g_cnt = jax.lax.psum(jnp.sum(c.count), PARTS_AXIS)
+            flags = jax.lax.psum(votes, PARTS_AXIS)
         use_dense = (
             (g_cnt > spec.nv // pspec.pull_threshold_den)
             | (flags[:2].max() > 0)
@@ -863,11 +908,13 @@ def _spmd_push_requeue(prog, pspec: PushSpec, spec: ShardSpec, qarr_blk,
         q_vid, q_val, cnt = jax.vmap(partial(build_queue, pspec))(
             qarr_blk, changed, new
         )
-        active = jax.lax.psum(jnp.sum(cnt), PARTS_AXIS)
-        # uint32 psum is exact: a sparse round's global total is bounded
-        # by sum_p e_sp_p ≈ ne/4 < 2^32 (bigger frontiers force dense)
-        g_total = jax.lax.psum(jnp.sum(totals.astype(jnp.uint32)),
-                               PARTS_AXIS)
+        with jax.named_scope("push.exchange"):
+            active = jax.lax.psum(jnp.sum(cnt), PARTS_AXIS)
+            # uint32 psum is exact: a sparse round's global total is
+            # bounded by sum_p e_sp_p ≈ ne/4 < 2^32 (bigger frontiers
+            # force dense)
+            g_total = jax.lax.psum(jnp.sum(totals.astype(jnp.uint32)),
+                                   PARTS_AXIS)
         edges = _acc_edges(c.edges, spec.ne, g_total, use_dense)
         sp_work, dense_rounds = _acc_load(c, totals, use_dense)
         return PushCarry(
@@ -917,7 +964,8 @@ def _allgather_dense_fn(prog, arr_blk, method, route_static=None,
     (optionally through the routed-shuffle expand — bitwise)."""
 
     def dense_fn(block):
-        full = flatten_gather(block)
+        with jax.named_scope("push.exchange"):
+            full = flatten_gather(block)
         if route_static is not None:
             return jax.vmap(
                 lambda arr, loc, ra: dense_part_step(
@@ -940,10 +988,8 @@ def _compile_push_dist(prog, mesh, pspec: PushSpec, spec: ShardSpec,
     carry_specs = _carry_specs()
     routed = route_static is not None
     in_specs = (arr_specs, parr_specs, carry_specs, P())
-    kw = {}
     if routed:
         in_specs = in_specs + (P(PARTS_AXIS),)
-        kw["check_vma"] = False  # pallas under shard_map (see dist.py)
 
     @jax.jit
     @partial(
@@ -951,7 +997,7 @@ def _compile_push_dist(prog, mesh, pspec: PushSpec, spec: ShardSpec,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=carry_specs,
-        **kw,
+        **methods.spmd_kw(method, routed),
     )
     def run(arr_blk, parr_blk, carry_blk, it_stop, *route_blk):
 
@@ -1032,6 +1078,7 @@ def _compile_push_phases_dist_cached(prog, mesh, pspec: PushSpec,
         mesh=mesh,
         in_specs=(arr_specs, parr_specs, carry_specs, plan_specs),
         out_specs=Pp,
+        **methods.spmd_kw(method),
     )
     def comp(arr_blk, parr_blk, c, plan):
         qv, qw, preps, use_dense, flags = plan
@@ -1058,13 +1105,43 @@ def _compile_push_phases_dist_cached(prog, mesh, pspec: PushSpec,
     return load, comp, update
 
 
+def place_push_shards(shards: PushShards, mesh: Mesh) -> PushShards:
+    """The shards with their stacked arrays laid over ``mesh``'s parts
+    axis, each device holding its own k = P / D parts and no more
+    (``shard_stacked``): a deployment places the graph once and then
+    runs every traversal on it (``run_push`` routes placed shards to
+    ``run_push_dist``).  Program span ``push.place``."""
+    with obs.span("push.place", parts=shards.spec.num_parts,
+                  devices=mesh.devices.size):
+        arrays, parrays = shard_stacked(mesh, (shards.arrays,
+                                               shards.parrays))
+        jax.block_until_ready((arrays, parrays))
+    return PushShards(pull=replace(shards.pull, arrays=arrays),
+                      pspec=shards.pspec, parrays=parrays)
+
+
+@lru_cache(maxsize=64)
+def _compile_init_dist(prog, mesh, pspec: PushSpec):
+    view_specs = VertexView(*([P(PARTS_AXIS)] * len(VertexView._fields)))
+    return jax.jit(jax.shard_map(
+        partial(_init_carry, prog, pspec), mesh=mesh,
+        in_specs=(view_specs,), out_specs=_carry_specs(),
+    ))
+
+
+def init_carry_dist(prog, pspec: PushSpec, view: VertexView, mesh: Mesh):
+    """The first carry built on the mesh, each device from its own parts'
+    rows of the placed vertex ``view``: no device holds another's."""
+    return _compile_init_dist(prog, mesh, pspec)(shard_stacked(mesh, view))
+
+
 def push_init_dist(prog, shards: PushShards, mesh: Mesh):
     """(arrays, parrays, carry0) sharded over the mesh for step-wise
-    distributed driving."""
-    arrays = shard_stacked(mesh, jax.tree.map(jnp.asarray, shards.arrays))
-    parrays = shard_stacked(mesh, jax.tree.map(jnp.asarray, shards.parrays))
-    carry0 = _init_carry(prog, shards.pspec, jax.tree.map(jnp.asarray, shards.arrays))
-    return arrays, parrays, shard_carry(mesh, carry0)
+    distributed driving.  Placed arrays are used as they are; host ones
+    go straight to their devices."""
+    arrays, parrays = shard_stacked(mesh, (shards.arrays, shards.parrays))
+    carry0 = init_carry_dist(prog, shards.pspec, vertex_view(arrays), mesh)
+    return arrays, parrays, carry0
 
 
 def shard_carry(mesh: Mesh, c: PushCarry) -> PushCarry:
@@ -1191,25 +1268,16 @@ def place_ring_statics(shards, mesh: Mesh):
     """Device-place the ring push engine's static arrays: only O(part
     edges) buckets/CSR and the O(V) vertex view — never the pull layout's
     O(E) stacked arrays.  Returns (rarrays, parrays, view)."""
-    rarrays = shard_stacked(mesh, jax.tree.map(jnp.asarray, shards.rarrays))
-    parrays = shard_stacked(mesh, jax.tree.map(jnp.asarray, shards.parrays))
-    view = shard_stacked(
-        mesh, jax.tree.map(jnp.asarray, vertex_view(shards.arrays))
+    return shard_stacked(
+        mesh, (shards.rarrays, shards.parrays, vertex_view(shards.arrays))
     )
-    return rarrays, parrays, view
 
 
 def ring_init_dist(prog, shards, mesh: Mesh):
     """(rarrays, parrays, view, carry0) sharded tuple for driving the
     ring push engine."""
     rarrays, parrays, view = place_ring_statics(shards, mesh)
-    carry0 = shard_carry(
-        mesh,
-        _init_carry(
-            prog, shards.pspec,
-            jax.tree.map(jnp.asarray, vertex_view(shards.arrays)),
-        ),
-    )
+    carry0 = init_carry_dist(prog, shards.pspec, view, mesh)
     return rarrays, parrays, view, carry0
 
 
@@ -1256,7 +1324,8 @@ def run_push_ring(
             )
             out = run(rarrays, parrays, view, carry0, jnp.int32(max_iters),
                       ra)
-    _count_rounds(out, method)
+    _count_rounds(out, method, exchange_bytes(
+        pspec, spec, mesh.devices.size, out.state))
     return out.state, out.it, out.edges
 
 
@@ -1288,15 +1357,13 @@ def run_push_dist(
                                      merge=merge)
             out = run(arrays, parrays, carry0, jnp.int32(max_iters))
         else:
-            from lux_tpu.engine.pull import _route_interpret
-            from lux_tpu.parallel.mesh import shard_stacked
+            from lux_tpu.parallel.mesh import routed_run_args
 
-            rs, ra = route
-            ra = shard_stacked(mesh, jax.tree.map(jnp.asarray, ra))
+            rs, ra, interp = routed_run_args(mesh, route)
             run = _compile_push_dist(prog, mesh, pspec, spec, method,
-                                     route_static=rs,
-                                     interpret=_route_interpret(),
+                                     route_static=rs, interpret=interp,
                                      merge=merge)
             out = run(arrays, parrays, carry0, jnp.int32(max_iters), ra)
-    _count_rounds(out, method)
+    _count_rounds(out, method, exchange_bytes(
+        pspec, spec, mesh.devices.size, out.state))
     return out.state, out.it, out.edges

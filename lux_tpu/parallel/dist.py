@@ -47,15 +47,12 @@ def _compile_fixed(prog, mesh, num_iters: int, method: str,
     phase through the routed-shuffle expand (parts share ONE static by
     construction, so the vmapped lanes stay uniform; the per-part index
     arrays ride in as a sharded pytree operand)."""
+    from lux_tpu.engine import methods
+
     routed = route_static is not None
     in_specs = (_arrays_specs(), P(PARTS_AXIS))
-    kw = {}
     if routed:
         in_specs = in_specs + (P(PARTS_AXIS),)
-        # pallas_call's out_shape carries no varying-mesh-axes
-        # annotation (see parallel/pallas_dist.py): the routed lane
-        # gathers run under this shard_map, so the vma check must be off
-        kw["check_vma"] = False
 
     @jax.jit
     @partial(
@@ -63,7 +60,7 @@ def _compile_fixed(prog, mesh, num_iters: int, method: str,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(PARTS_AXIS),
-        **kw,
+        **methods.spmd_kw(method, routed),
     )
     def run(arr_blk, state_blk, *route_blk):
         # each device holds k = P/D resident parts (k == 1 when P == D);
@@ -109,12 +106,12 @@ def run_pull_fixed_dist(
 
     method = methods.resolve_sum(method, prog.reduce)
     assert spec.num_parts % mesh.devices.size == 0, (spec.num_parts, mesh.shape)
-    arrays = shard_stacked(mesh, jax.tree.map(jnp.asarray, arrays))
+    arrays = shard_stacked(mesh, arrays)
     state0 = shard_stacked(mesh, state0)
     if route is None:
         return _compile_fixed(prog, mesh, num_iters, method)(arrays, state0)
     rs, ra = route
-    ra = shard_stacked(mesh, jax.tree.map(jnp.asarray, ra))
+    ra = shard_stacked(mesh, ra)
     fn = _compile_fixed(prog, mesh, num_iters, method, route_static=rs,
                         interpret=_route_interpret())
     return fn(arrays, state0, ra)
@@ -146,6 +143,8 @@ def compile_pull_phases_dist(prog, mesh, method: str = "auto"):
 
 @lru_cache(maxsize=64)
 def _compile_phases_dist_cached(prog, mesh, method: str):
+    from lux_tpu.engine import methods
+
     Pp = P(PARTS_AXIS)
 
     @jax.jit
@@ -167,6 +166,7 @@ def _compile_phases_dist_cached(prog, mesh, method: str):
         mesh=mesh,
         in_specs=(_arrays_specs(), (Pp, Pp)),
         out_specs=Pp,
+        **methods.spmd_kw(method),
     )
     def comp(arr_blk, gath_blk):
         return jax.vmap(
@@ -190,12 +190,15 @@ def _compile_phases_dist_cached(prog, mesh, method: str):
 
 @lru_cache(maxsize=64)
 def _compile_until(prog, mesh, max_iters: int, active_fn, method: str):
+    from lux_tpu.engine import methods
+
     @jax.jit
     @partial(
         jax.shard_map,
         mesh=mesh,
         in_specs=(_arrays_specs(), P(PARTS_AXIS)),
         out_specs=(P(PARTS_AXIS), P()),
+        **methods.spmd_kw(method),
     )
     def run(arr_blk, state_blk):
 
@@ -246,7 +249,7 @@ def run_pull_until_dist(
 
     method = methods.resolve_sum(method, prog.reduce)
     assert spec.num_parts % mesh.devices.size == 0, (spec.num_parts, mesh.shape)
-    arrays = shard_stacked(mesh, jax.tree.map(jnp.asarray, arrays))
+    arrays = shard_stacked(mesh, arrays)
     state0 = shard_stacked(mesh, state0)
     return _compile_until(prog, mesh, max_iters, active_fn, method)(
         arrays, state0

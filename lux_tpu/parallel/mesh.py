@@ -68,9 +68,32 @@ def parts_sharding(mesh: Mesh) -> NamedSharding:
 
 
 def shard_stacked(mesh: Mesh, tree):
-    """Place a pytree of stacked (P, ...) arrays with axis 0 on the mesh."""
+    """Place a pytree of stacked (P, ...) arrays with axis 0 on the mesh.
+
+    Host (NumPy) leaves go straight to their devices, each device its
+    own k = P / D parts: nothing is staged whole on one device first.  A
+    leaf already laid out this way is returned as it is (no copy)."""
     sh = parts_sharding(mesh)
-    return jax.tree.map(lambda a: jax.device_put(a, sh), tree)
+
+    def put(a):
+        if isinstance(a, jax.Array) and a.sharding.is_equivalent_to(
+                sh, a.ndim):
+            return a
+        return jax.device_put(a, sh)
+
+    return jax.tree.map(put, tree)
+
+
+def parts_mesh(tree) -> Optional[Mesh]:
+    """The mesh of more than one device whose parts axis the stacked
+    tree's arrays are laid over, else None (host arrays, one device).
+    Reads the first leaf's sharding: no device sync."""
+    leaves = jax.tree.leaves(tree)
+    sh = getattr(leaves[0], "sharding", None) if leaves else None
+    if (isinstance(sh, NamedSharding) and sh.mesh.size > 1
+            and sh.spec and sh.spec[0] == PARTS_AXIS):
+        return sh.mesh
+    return None
 
 
 def flatten_gather(block):
@@ -88,11 +111,7 @@ def routed_run_args(mesh, route):
     """Shared tail for routed exchange drivers: device-shard the plan
     arrays over the parts axis and resolve interpret mode.  Returns
     (route_static, sharded_arrays, interpret)."""
-    import jax
-    import jax.numpy as jnp
-
     from lux_tpu.engine.pull import _route_interpret
 
     rs, ra = route
-    ra = shard_stacked(mesh, jax.tree.map(jnp.asarray, ra))
-    return rs, ra, _route_interpret()
+    return rs, shard_stacked(mesh, ra), _route_interpret()

@@ -223,7 +223,7 @@ def run_pull_fixed_pallas_dist(
     stacked (P, V); returns the final stacked (sharded) state."""
     _guard(prog)
     assert pp.spec.num_parts == mesh.devices.size
-    arrays = shard_stacked(mesh, jax.tree.map(jnp.asarray, pp.arrays))
+    arrays = shard_stacked(mesh, pp.arrays)
     state0 = shard_stacked(mesh, state0)
     # bf16 state programs also feed the MXU at the bf16 rate (f32
     # accumulation either way) — match the single-chip runner's contract
@@ -406,13 +406,11 @@ def run_push_pallas_dist(
         )
     spec, pspec = shards.spec, shards.pspec
     assert spec.num_parts == mesh.devices.size
-    pl = shard_stacked(mesh, jax.tree.map(jnp.asarray, shards.pl))
-    parrays = shard_stacked(
-        mesh, jax.tree.map(jnp.asarray, shards.push.parrays)
+    pl, parrays, view = shard_stacked(
+        mesh, (shards.pl, shards.push.parrays,
+               pe.vertex_view(shards.push.arrays))
     )
-    view_h = jax.tree.map(jnp.asarray, pe.vertex_view(shards.push.arrays))
-    view = shard_stacked(mesh, view_h)
-    carry0 = pe.shard_carry(mesh, pe._init_carry(prog, pspec, view_h))
+    carry0 = pe.init_carry_dist(prog, pspec, view, mesh)
     run = _compile_push_pallas(
         prog, mesh, pspec, spec, shards.num_vblocks, shards.v_blk, interpret
     )
@@ -440,7 +438,7 @@ def run_cf_pallas_dist(
     if not pp.spec.weighted:
         raise ValueError("CF requires a weighted graph")
     assert pp.spec.num_parts == mesh.devices.size
-    arrays = shard_stacked(mesh, jax.tree.map(jnp.asarray, pp.arrays))
+    arrays = shard_stacked(mesh, pp.arrays)
     state0 = shard_stacked(mesh, state0)
     return _compile_fixed_pallas_2d(
         prog, mesh, num_iters, pp.num_vblocks, pp.v_blk, pp.spec.nv_pad,

@@ -228,3 +228,71 @@ def test_bfs_dense_step_reduces_with_the_kernel(one_chip, as_on_tpu):
         _sds(one_chip, (1, NV), jnp.int32)).compile()
     assert _kernels(c) == 1
     assert _no_scatter(c.as_text())
+
+
+@pytest.fixture(scope="module")
+def four_chips(one_chip):
+    """A parts mesh over the described v5e 2x2 (compile cache off, as
+    ``one_chip`` leaves it)."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return Mesh(np.asarray(topo.devices[:4]), ("parts",))
+
+
+def test_distributed_bfs_loop_compiles_for_four_chips(four_chips,
+                                                      as_on_tpu):
+    """The four-chip BFS cell's loop (-ng 4 --distributed) with the TPU's
+    auto method: the dense rounds' scan kernel runs under the shard_map
+    (one kernel), and the exchange's collectives are in the program."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from lux_tpu.engine import methods, push
+    from lux_tpu.graph.push_shards import PushArrays, PushSpec
+    from lux_tpu.graph.shards import ShardArrays, ShardSpec
+    from lux_tpu.models.sssp import SSSPProgram
+
+    nv, v, e, u, parts = 1 << 16, 1 << 14, 1 << 19, 1 << 14, 4
+    sh = NamedSharding(four_chips, PartitionSpec("parts"))
+    rep = NamedSharding(four_chips, PartitionSpec())
+
+    def sds(shape, dtype, s=sh):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=s)
+
+    arrays = ShardArrays(
+        row_ptr=sds((parts, v + 1), jnp.int32),
+        src_pos=sds((parts, e), jnp.int32),
+        dst_local=sds((parts, e), jnp.int32),
+        head_flag=sds((parts, e), jnp.bool_),
+        edge_mask=sds((parts, e), jnp.bool_),
+        vtx_mask=sds((parts, v), jnp.bool_),
+        degree=sds((parts, v), jnp.int32),
+        global_vid=sds((parts, v), jnp.int32),
+        weights=sds((parts, e), jnp.float32),
+        mirror_pos=sds((parts, 0), jnp.int32),
+        mirror_rel=sds((parts, 0), jnp.int32))
+    parrays = PushArrays(sds((parts, u), jnp.int32),
+                         sds((parts, u + 1), jnp.int32),
+                         sds((parts, e), jnp.int32),
+                         sds((parts, e), jnp.float32))
+    pspec = PushSpec(u_pad=u, f_cap=1152, e_sp=e // 4 + 128,
+                     e_sp_small=e // 64)
+    carry = push.PushCarry(
+        sds((parts, v), jnp.int32), sds((parts, 1152), jnp.int32),
+        sds((parts, 1152), jnp.int32), sds((parts,), jnp.int32),
+        sds((), jnp.int32, rep), sds((), jnp.int32, rep),
+        sds((2,), jnp.uint32, rep), sds((parts,), jnp.uint32),
+        sds((), jnp.int32, rep))
+    prog = SSSPProgram(nv=nv, start=0)
+    method = methods.resolve_sum("auto", prog.reduce, "tpu")
+    assert method == "mxscan"
+    run = push._compile_push_dist(prog, four_chips, pspec,
+                                  ShardSpec(parts, nv, parts * e, v, e,
+                                            False), method)
+    c = run.lower(arrays, parrays, carry,
+                  sds((), jnp.int32, rep)).compile()
+    text = c.as_text()
+    assert _kernels(c) == 1
+    assert "all-gather" in text and "all-reduce" in text
